@@ -51,6 +51,8 @@ def run(algo):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
     allrows = []
     for algo in ("ssgd", "ssgd_star", "dpsgd"):

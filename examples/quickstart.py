@@ -41,4 +41,6 @@ def main(*, steps: int = STEPS, local_batch: int = LOCAL_BATCH):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -10,6 +10,9 @@ starts after ``warmup()`` (compile excluded) and each step host-syncs on
 the logits, so the tok/s figure is honest wall-clock.
 
     PYTHONPATH=src python examples/serve_batched.py --arch gemma2-27b
+
+``--preset full`` serves the architecture at its own widths (random
+weights); the default ``smoke`` uses its reduced config.
 """
 import argparse
 import time
@@ -22,9 +25,37 @@ from repro.models import build_model
 from repro.serve import ServeEngine
 
 
+def build_engine(arch: str = "transformer-100m", preset: str = "smoke", *,
+                 slots: int = 4, page: int = 8, buf: int = 64, seed: int = 0):
+    """A ServeEngine over ``arch`` with weights from ``seed``: its own
+    widths with ``preset='full'``, its reduced config with ``'smoke'``."""
+    cfg = get_config(arch)
+    if preset == "smoke":
+        cfg = cfg.smoke_config()
+    api = build_model(cfg)
+    if not api.has_paged:
+        raise SystemExit(f"{cfg.name}: family {cfg.family} has no paged "
+                         "decode path (text families only)")
+    params = api.init(jax.random.PRNGKey(seed))
+    return ServeEngine(api, params, n_slots=slots, page_size=page,
+                       max_len=buf)
+
+
+def submit_mixed(eng, n_req: int, new_tokens: int, seed: int = 0):
+    """Queue ``n_req`` prompts of random length (1 .. buf - new_tokens)."""
+    rng = np.random.default_rng(seed)
+    vocab = eng.api.cfg.vocab
+    max_prompt = max(1, eng.max_len - new_tokens)
+    return [eng.submit(rng.integers(1, vocab,
+                                    rng.integers(1, max_prompt + 1)).tolist(),
+                       new_tokens)
+            for _ in range(n_req)]
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="transformer-100m")
+    ap.add_argument("--preset", choices=["full", "smoke"], default="smoke")
     ap.add_argument("--batch", type=int, default=4, help="decode slots")
     ap.add_argument("--new-tokens", type=int, default=32)
     ap.add_argument("--buf", type=int, default=64,
@@ -34,22 +65,11 @@ def main():
                     help="requests to serve (default: 2x slots)")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).smoke_config()
-    api = build_model(cfg)
-    if not api.has_paged:
-        raise SystemExit(f"{cfg.name}: family {cfg.family} has no paged "
-                         "decode path (text families only)")
-    params = api.init(jax.random.PRNGKey(0))
-
-    eng = ServeEngine(api, params, n_slots=args.batch, page_size=args.page,
-                      max_len=args.buf)
-    rng = np.random.default_rng(0)
+    eng = build_engine(args.arch, args.preset, slots=args.batch,
+                       page=args.page, buf=args.buf)
+    cfg = eng.api.cfg
     n_req = args.requests or 2 * args.batch
-    max_prompt = max(1, args.buf - args.new_tokens)
-    reqs = [eng.submit(rng.integers(1, cfg.vocab,
-                                    rng.integers(1, max_prompt + 1)).tolist(),
-                       args.new_tokens)
-            for _ in range(n_req)]
+    reqs = submit_mixed(eng, n_req, args.new_tokens)
 
     eng.warmup()                      # compile outside the timed region
     t0 = time.perf_counter()
@@ -68,4 +88,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

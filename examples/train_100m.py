@@ -8,7 +8,6 @@ CPU-friendly demo (default): reduced seq/batch, same 100M architecture.
 CI smoke: --preset smoke uses the reduced config.
 """
 import argparse
-import os
 import time
 
 import jax
@@ -20,6 +19,32 @@ from repro.core import AlgoConfig, MultiLearnerTrainer
 from repro.data import ShardedLoader, SyntheticTokenStream
 from repro.models import build_model
 from repro.optim import sgd, scale_by_schedule, warmup_linear_scale
+
+
+def build(preset: str = "full", *, learners: int = 4, local_batch: int = 2,
+          seq: int = 64, lr: float = 0.5, seed: int = 0):
+    """The example's model, data and DPSGD trainer, from a seed.
+
+    Returns (cfg, api, loader, trainer, state); ``preset='full'`` is
+    transformer-100m at its own widths, ``'smoke'`` its reduced config.
+    """
+    cfg = get_config("transformer-100m")
+    if preset == "smoke":
+        cfg = cfg.smoke_config()
+    api = build_model(cfg)
+    ds = SyntheticTokenStream(vocab=cfg.vocab)
+    loader = ShardedLoader(ds, n_learners=learners, local_batch=local_batch,
+                           extra_args=(seq,))
+    # paper recipe: warmup + linear scaling, DPSGD random-neighbor gossip
+    opt = scale_by_schedule(sgd(lr, momentum=0.9),
+                            warmup_linear_scale(10, 1.0))
+    trainer = MultiLearnerTrainer(
+        api.loss_fn, opt,
+        AlgoConfig(algo="dpsgd", topology="random_pair",
+                   n_learners=learners))
+    key = jax.random.PRNGKey(seed)
+    state = trainer.init(key, api.init(key))
+    return cfg, api, loader, trainer, state
 
 
 def main():
@@ -34,28 +59,13 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=20)
     args = ap.parse_args()
 
-    cfg = get_config("transformer-100m")
-    if args.preset == "smoke":
-        cfg = cfg.smoke_config()
-    api = build_model(cfg)
+    cfg, api, loader, trainer, state = build(
+        args.preset, learners=args.learners, local_batch=args.local_batch,
+        seq=args.seq, lr=args.lr)
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(
         jax.eval_shape(api.init, jax.random.PRNGKey(0))))
     print(f"model: {cfg.name}  params={n_params / 1e6:.1f}M  "
           f"learners={args.learners}  nB={args.learners * args.local_batch}")
-
-    ds = SyntheticTokenStream(vocab=cfg.vocab)
-    loader = ShardedLoader(ds, n_learners=args.learners,
-                           local_batch=args.local_batch,
-                           extra_args=(args.seq,))
-    # paper recipe: warmup + linear scaling, DPSGD random-neighbor gossip
-    opt = scale_by_schedule(sgd(args.lr, momentum=0.9),
-                            warmup_linear_scale(10, 1.0))
-    trainer = MultiLearnerTrainer(
-        api.loss_fn, opt,
-        AlgoConfig(algo="dpsgd", topology="random_pair",
-                   n_learners=args.learners))
-    key = jax.random.PRNGKey(0)
-    state = trainer.init(key, api.init(key))
 
     def ckpt_tree(st):
         # checkpoint the pytree VIEW so checkpoints stay layout-stable
@@ -86,4 +96,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

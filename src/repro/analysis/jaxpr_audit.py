@@ -23,6 +23,8 @@ from typing import List, Optional
 
 import jax
 import numpy as np
+from jax.extend.core import ClosedJaxpr as _ClosedJaxpr
+from jax.extend.core import Jaxpr as _Jaxpr
 
 from .report import Finding, rule
 
@@ -32,11 +34,6 @@ __all__ = [
     "donation_honored", "aliased_param_bytes", "HOST_CALLBACK_PRIMITIVES",
 ]
 
-try:                                      # jax >= 0.6 moved these
-    from jax.extend.core import ClosedJaxpr as _ClosedJaxpr
-    from jax.extend.core import Jaxpr as _Jaxpr
-except (ImportError, AttributeError):     # pragma: no cover - old jax
-    _ClosedJaxpr, _Jaxpr = jax.core.ClosedJaxpr, jax.core.Jaxpr
 
 # primitives that round-trip through the host inside a traced computation:
 # one of these in a hot step means a device->host->device sync per call

@@ -35,15 +35,17 @@ def _bytes_of(tree) -> int:
 
 
 def live_slots(schedule) -> int:
-    """Non-padded neighbor slots across a compiled schedule's period — the
-    exact collective budget (one permute per slot, leaf count does not
-    multiply it; see tests/test_gossip_schedule_launch.py)."""
-    n = schedule.n
-    idx = np.arange(n)
+    """Distinct live neighbor permutations per round, summed over a compiled
+    schedule's period — the exact collective budget (one permute per
+    permutation: padded slots cost nothing, slots repeating a permutation
+    share one, and leaf count does not multiply it; see
+    tests/test_gossip_schedule_launch.py)."""
+    idx = np.arange(schedule.n)
     return int(sum(
-        0 if ((schedule.partners[r, k] == idx).all()
-              and not schedule.coefs[r][:, 1 + k].any()) else 1
-        for r in range(schedule.period) for k in range(schedule.K)))
+        len({tuple(schedule.partners[r, k]) for k in range(schedule.K)
+             if not ((schedule.partners[r, k] == idx).all()
+                     and not schedule.coefs[r][:, 1 + k].any())})
+        for r in range(schedule.period)))
 
 
 # ---------------------------------------------------------------------------
@@ -123,10 +125,11 @@ def audit_launch(arch: str = "transformer-100m") -> List[Finding]:
     from repro.core.flatstate import flat_meta
     from repro.core.schedule import make_schedule
     from repro.launch import sharding as shd
+    from repro.launch.mesh import make_test_mesh
     from repro.launch.train import (jit_train_step, make_dpsgd_train_step,
                                     train_state_specs, train_state_shardings)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_test_mesh(4, 2)
     cfg = get_config(arch).smoke_config()
     from repro.models.model import build_model
     from repro.optim import sgd
@@ -160,7 +163,7 @@ def audit_launch(arch: str = "transformer-100m") -> List[Finding]:
     findings += collective_count(jxp, expected=expected, target=target)
     findings += wire_dtype(jxp, expected=wire, target=target)
 
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jit_train_step(
             step, in_shardings=shd.named_shardings((shds, bshd), mesh),
             out_shardings=shd.named_shardings((shds, None), mesh),

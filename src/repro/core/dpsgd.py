@@ -101,8 +101,12 @@ def mix_einsum(stacked, m):
         # ellipsis einsum keeps trailing (model-sharded) dims intact — a
         # flatten here would destroy the tensor-parallel sharding and force
         # XLA to replicate every leaf (measured: 96 GB -> 1.6 GB temp).
+        # HIGHEST: at default precision the TPU's MXU rounds both the
+        # weights and the mixing coefficients (1/3 on a ring) to bfloat16;
+        # the average must stay f32-exact
         out = jnp.einsum("ij,j...->i...", m.astype(jnp.float32),
-                         x.astype(jnp.float32))
+                         x.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
         return out.astype(x.dtype)
     return jax.tree_util.tree_map(_mix, stacked)
 
@@ -259,14 +263,19 @@ def _schedule_perms(schedule):
 def _schedule_round_mix(x, axis_names, schedule, perms, r: int, idx):
     """One STATIC round ``r`` of the schedule on a local array ``x``:
     gather each neighbor slot with a collective-permute and accumulate in
-    f32 with the same term order as the fused kernel/einsum tables."""
+    f32 with the same term order as the fused kernel/einsum tables.  Slots
+    sharing one permutation (a 2-row torus's up/down) share its collective:
+    one permute per distinct live permutation."""
     coefs = jnp.asarray(schedule.coefs[r])
     acc = coefs[idx, 0] * x.astype(jnp.float32)
+    received = {}
     for k, perm in enumerate(perms[r]):
         if perm is None:
             continue
-        other = jax.lax.ppermute(x, axis_names, perm)
-        acc = acc + coefs[idx, 1 + k] * other.astype(jnp.float32)
+        key = tuple(perm)
+        if key not in received:
+            received[key] = jax.lax.ppermute(x, axis_names, perm)
+        acc = acc + coefs[idx, 1 + k] * received[key].astype(jnp.float32)
     return acc
 
 

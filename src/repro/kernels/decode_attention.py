@@ -28,10 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names it TPUCompilerParams; >= 0.6 renamed to CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
 
 
@@ -48,8 +44,8 @@ def _decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32)             # (G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)       # (page, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)             # (page, hd)
+    v = v_ref[0, 0].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -83,10 +79,14 @@ def _decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, lengths, *,
                                window: int = 0, attn_softcap: float = 0.0,
                                interpret: bool = False):
-    """q: (S, H, hd); k_pages, v_pages: (P, page, KV, hd);
-    page_table: (S, max_pages) int32; lengths: (S,) int32 -> (S, H, hd)."""
+    """q: (S, H, hd); k_pages, v_pages: (P, KV, page, hd);
+    page_table: (S, max_pages) int32; lengths: (S,) int32 -> (S, H, hd).
+
+    Head-major pages make one (page, hd) K/V tile the block's last two
+    dims, equal to the pool's own — legal under the TPU block rule for any
+    page size (a (page, 1, hd) slice of a (page, KV, hd) page is not)."""
     S, H, hd = q.shape
-    P, page, KV, _ = k_pages.shape
+    P, KV, page, _ = k_pages.shape
     G = H // KV
     max_pages = page_table.shape[1]
     grid = (S, KV, max_pages)
@@ -103,10 +103,10 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, lengths, *,
             in_specs=[
                 pl.BlockSpec((1, 1, G, hd),
                              lambda s, kv, j, tbl, ln: (s, kv, 0, 0)),
-                pl.BlockSpec((1, page, 1, hd),
-                             lambda s, kv, j, tbl, ln: (tbl[s, j], 0, kv, 0)),
-                pl.BlockSpec((1, page, 1, hd),
-                             lambda s, kv, j, tbl, ln: (tbl[s, j], 0, kv, 0)),
+                pl.BlockSpec((1, 1, page, hd),
+                             lambda s, kv, j, tbl, ln: (tbl[s, j], kv, 0, 0)),
+                pl.BlockSpec((1, 1, page, hd),
+                             lambda s, kv, j, tbl, ln: (tbl[s, j], kv, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, G, hd),
                                    lambda s, kv, j, tbl, ln: (s, kv, 0, 0)),
@@ -116,7 +116,7 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, lengths, *,
                 pltpu.VMEM((G, hd), jnp.float32),
             ]),
         out_shape=jax.ShapeDtypeStruct((S, KV, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), qg,

@@ -102,8 +102,9 @@ def _flat_kernel(part_ref, *refs, n_neighbors: int, lr: float, beta: float,
     refs layout:
       w, nbr_w_0..K-1, [nbr_buf], g, [mu], [buf], coefs,
       w_out, [mu_out], [buf_out]
-    coefs (SMEM): [self, neighbor..., controller scale, active] — plus, in
-    publish mode, [nbr_fresh, publish].
+    coefs (SMEM, all n rows; row ``program_id(0)`` is this learner's):
+    [self, neighbor..., controller scale, active] — plus, in publish mode,
+    [nbr_fresh, publish].
 
     ``active`` (0/1) folds the AD-PSGD straggler select into the same pass:
     an inactive learner's weights and momentum stream through unchanged
@@ -128,20 +129,21 @@ def _flat_kernel(part_ref, *refs, n_neighbors: int, lr: float, beta: float,
     mu_out = next(it) if has_momentum else None
     buf_out = next(it) if publish else None
 
+    i = pl.program_id(0)                 # this tile's learner
     w = w_ref[0]
-    mixed = coef_ref[0, 0] * w
+    mixed = coef_ref[i, 0] * w
     for j in range(k):
         nbr = nbr_refs[j][0]
         if publish:
-            nbr = jnp.where(coef_ref[0, 3 + k] > 0.5, nbr, nbr_buf_ref[0])
-        mixed += coef_ref[0, 1 + j] * nbr
+            nbr = jnp.where(coef_ref[i, 3 + k] > 0.5, nbr, nbr_buf_ref[0])
+        mixed += coef_ref[i, 1 + j] * nbr
     g = g_ref[0]
     if weight_decay:
         g = g + weight_decay * w
-    lr_eff = lr * coef_ref[0, 1 + k]
+    lr_eff = lr * coef_ref[i, 1 + k]
     # where, not arithmetic blend: a mid-divergence NaN in the discarded
     # branch must not leak through 0 * NaN
-    active = coef_ref[0, 2 + k] > 0.5
+    active = coef_ref[i, 2 + k] > 0.5
     if has_momentum:
         mu = mu_ref[0]
         mu_new = beta * mu + g
@@ -151,7 +153,7 @@ def _flat_kernel(part_ref, *refs, n_neighbors: int, lr: float, beta: float,
         new_w = jnp.where(active, mixed - lr_eff * g, w)
     w_out[0] = new_w
     if publish:
-        buf_out[0] = jnp.where(coef_ref[0, 4 + k] > 0.5, new_w, buf_ref[0])
+        buf_out[0] = jnp.where(coef_ref[i, 4 + k] > 0.5, new_w, buf_ref[0])
 
 
 def _pick_rows(T: int, block_rows: int) -> int:
@@ -221,8 +223,10 @@ def gossip_mix_update_flat(w, remote, grads, momentum, partners, coefs, *,
     def nbr_spec(k):
         return pl.BlockSpec((1, rows, LANE), lambda i, j, p: (p[k, i], j, 0))
 
-    coef_spec = pl.BlockSpec((1, ncoef), lambda i, j, p: (i, 0),
-                             memory_space=pltpu.SMEM)
+    # the whole (n, ncoef) table sits in SMEM; the kernel reads its own
+    # learner's row (a (1, ncoef) block would break the TPU block rule:
+    # last two block dims divisible by (8, 128) or equal to the array's)
+    coef_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     kern = functools.partial(_flat_kernel, n_neighbors=K, lr=lr, beta=beta,
                              weight_decay=weight_decay,

@@ -80,7 +80,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     """Paged serving decode attention (ISSUE 7, DESIGN §14).
 
     q: (S, H, hd) — one query token per serve slot; k_pages, v_pages:
-    (P, page, KV, hd) shared pools; page_table: (S, max_pages) int32
+    (P, KV, page, hd) shared head-major pools; page_table: (S, max_pages) int32
     physical page ids in logical order; lengths: (S,) int32 valid tokens
     per slot (current token included).  ``backend='auto'`` follows the
     repo's kernel/oracle/dispatch rule: the Mosaic kernel on accelerators,

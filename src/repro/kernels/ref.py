@@ -79,9 +79,9 @@ def paged_decode_attention_ref(q, k_pages, v_pages, page_table, lengths, *,
     """Same contract as kernels.ops.paged_decode_attention.
 
     q: (S, H, hd) one query token per slot; k_pages, v_pages:
-    (P, page, KV, hd) shared page pools; page_table: (S, max_pages) int32
-    physical page ids in logical order; lengths: (S,) int32 valid tokens
-    per slot (including the current one).  Gathers each slot's logical
+    (P, KV, page, hd) shared head-major page pools; page_table:
+    (S, max_pages) int32 physical page ids in logical order; lengths: (S,)
+    int32 valid tokens per slot (including the current one).  Gathers each slot's logical
     (W = max_pages * page) KV buffer through its table row, then runs the
     exact einsum/softmax chain of models.attention.attn_decode so the paged
     and rotating decode paths stay bitwise equal on CPU (the test pin).
@@ -90,11 +90,16 @@ def paged_decode_attention_ref(q, k_pages, v_pages, page_table, lengths, *,
     softmax over the masked row — finite garbage the scheduler ignores.
     """
     S, H, hd = q.shape
-    P, page, KV, _ = k_pages.shape
+    P, KV, page, _ = k_pages.shape
     G = H // KV
     W = page_table.shape[1] * page
-    kc = k_pages[page_table].reshape(S, W, KV, hd)
-    vc = v_pages[page_table].reshape(S, W, KV, hd)
+
+    def logical(pages):     # (S, max_pages, KV, page, hd) -> (S, W, KV, hd)
+        return pages[page_table].transpose(0, 1, 3, 2, 4).reshape(
+            S, W, KV, hd)
+
+    kc = logical(k_pages)
+    vc = logical(v_pages)
     qg = q.reshape(S, KV, G, hd)
     s = jnp.einsum("bkgd,bwkd->bkgw", qg.astype(jnp.float32),
                    kc.astype(jnp.float32)) * hd ** -0.5

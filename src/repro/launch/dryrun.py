@@ -68,7 +68,7 @@ def build_lowered(arch: str, shape: str, *, multi_pod: bool, algo: str,
                                          gossip_backend=backend)
         else:
             step = make_ssgd_train_step(api, opt, mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jit_train_step(
                 step,
                 in_shardings=shd.named_shardings((state_shd, batch_shd), mesh),
@@ -84,7 +84,7 @@ def build_lowered(arch: str, shape: str, *, multi_pod: bool, algo: str,
         batch_specs = api.train_batch_spec(global_batch, seq_len)
         batch_shd = shd.batch_sharding(batch_specs, mesh, stacked=False)
         step = make_prefill_step(api)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(
                 step,
                 in_shardings=shd.named_shardings((params_shd, batch_shd), mesh),
@@ -112,7 +112,7 @@ def build_lowered(arch: str, shape: str, *, multi_pod: bool, algo: str,
     tok_shd = shd.batch_sharding(tok_spec, mesh, stacked=False)
     pos_spec = jax.ShapeDtypeStruct((), jnp.int32)
     step = make_decode_step(api)
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(
             step,
             in_shardings=shd.named_shardings(

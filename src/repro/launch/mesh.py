@@ -13,12 +13,22 @@ device state (XLA_FLAGS must be set before first jax init in dryrun).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with Auto axes.  Step builders place their operands
+    through in/out shardings and leave the rest to XLA's propagation, so
+    the models' activation hints (models/shard_hints.py) stay constraints
+    under ``jax.set_mesh`` instead of Explicit-mode asserts."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def learner_axes(mesh) -> tuple:
@@ -35,4 +45,4 @@ def n_learners(mesh) -> int:
 
 def make_test_mesh(n_data: int = 4, n_model: int = 2):
     """Small mesh for CPU tests (requires forced host device count)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))

@@ -28,11 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                      # jax >= 0.6
-    _shard_map = jax.shard_map
-except AttributeError:                    # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..core.dpsgd import (member_active_mask, mix_einsum, mix_ppermute_pair,
                           mix_ppermute_pair_flat, mix_ppermute_ring,
                           mix_ppermute_ring_flat, mix_ppermute_schedule,
@@ -138,9 +133,9 @@ def make_dpsgd_train_step(api: ModelAPI, optimizer: Optimizer, mesh,
         # replication inference — the mix itself never touches the model
         # axes (every model shard runs the identical elementwise program),
         # so the check is soundly skipped (DESIGN §11)
-        return _shard_map(local, mesh=mesh, in_specs=(specs,),
+        return jax.shard_map(local, mesh=mesh, in_specs=(specs,),
                              out_specs=specs,
-                             check_rep=gossip_fuse != "flat")(params)
+                             check_vma=gossip_fuse != "flat")(params)
 
     def train_step(state: PjitTrainState, batch):
         # batch leaves: (GB, ...) -> (L, B_local, ...)
@@ -232,12 +227,12 @@ def make_adpsgd_train_step(api: ModelAPI, optimizer: Optimizer, mesh, *,
         if act is not None:
             in_specs += (age_spec, P())
             args += (act, drop)
-        # check_rep: see make_dpsgd_train_step — the flat view breaks static
+        # check_vma: see make_dpsgd_train_step — the flat view breaks static
         # replication inference, not actual replication
-        return _shard_map(local, mesh=mesh,
+        return jax.shard_map(local, mesh=mesh,
                              in_specs=in_specs,
                              out_specs=specs,
-                             check_rep=gossip_fuse != "flat")(*args)
+                             check_vma=gossip_fuse != "flat")(*args)
 
     def train_step(state: PjitTrainState, batch):
         stacked_batch = jax.tree_util.tree_map(

@@ -171,8 +171,8 @@ def init_paged_attn_cache(n_pages: int, page_size: int, n_kv: int,
     there and length masks keep it from ever being read.
     """
     return {
-        "k_pages": jnp.zeros((n_pages, page_size, n_kv, head_dim), dtype),
-        "v_pages": jnp.zeros((n_pages, page_size, n_kv, head_dim), dtype),
+        "k_pages": jnp.zeros((n_pages, n_kv, page_size, head_dim), dtype),
+        "v_pages": jnp.zeros((n_pages, n_kv, page_size, head_dim), dtype),
     }
 
 
@@ -196,7 +196,7 @@ def attn_decode_paged(params, cache, x, positions, page_table, *,
     from ..kernels.ops import paged_decode_attention
 
     S = x.shape[0]
-    page = cache["k_pages"].shape[1]
+    page = cache["k_pages"].shape[2]
     q = (x @ params["wq"]).reshape(S, 1, n_heads, head_dim)
     k = (x @ params["wk"]).reshape(S, 1, n_kv, head_dim)
     v = (x @ params["wv"]).reshape(S, 1, n_kv, head_dim)
@@ -209,9 +209,9 @@ def attn_decode_paged(params, cache, x, positions, page_table, *,
     ppage = jnp.take_along_axis(page_table, (positions // page)[:, None],
                                 axis=1)[:, 0]
     off = positions % page
-    kc = cache["k_pages"].at[ppage, off].set(
+    kc = cache["k_pages"].at[ppage, :, off].set(
         k[:, 0].astype(cache["k_pages"].dtype))
-    vc = cache["v_pages"].at[ppage, off].set(
+    vc = cache["v_pages"].at[ppage, :, off].set(
         v[:, 0].astype(cache["v_pages"].dtype))
 
     o = paged_decode_attention(q.reshape(S, n_heads, head_dim), kc, vc,

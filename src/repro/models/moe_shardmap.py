@@ -30,10 +30,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-try:                                      # jax >= 0.6
-    _shard_map = jax.shard_map
-except AttributeError:                    # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 from .shard_hints import (axis_size, batch_axes, current_mesh, has_axis,
@@ -138,7 +134,7 @@ def moe_forward_shardmap(params, x, *, n_experts: int, top_k: int,
         return y.reshape(bl, sl, d)
 
     x_spec = P(b_shard, "model", None)
-    return _shard_map(
+    return jax.shard_map(
         wrapper,
         mesh=current_mesh(),
         in_specs=(x_spec, P(None, None), P("model", None, None),

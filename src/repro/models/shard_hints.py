@@ -9,29 +9,14 @@ from jax.sharding import PartitionSpec as P
 
 
 def current_mesh():
-    """The mesh of the surrounding `with mesh:` / set_mesh context, or None.
-
-    jax >= 0.6 exposes it as the abstract mesh; on jax 0.4.x fall back to
-    the thread-local physical mesh the context manager installs.
-    """
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if tuple(getattr(am, "axis_names", ()) or ()):
-            return am
-    except AttributeError:
-        pass
-    try:
-        pm = jax._src.mesh.thread_resources.env.physical_mesh
-        if not pm.empty:
-            return pm
-    except Exception:  # pragma: no cover - internal layout changed
-        pass
-    return None
+    """The abstract mesh of the surrounding ``jax.set_mesh`` context, or
+    None outside one."""
+    am = jax.sharding.get_abstract_mesh()
+    return am if am.axis_names else None
 
 
 def mesh_axes() -> tuple:
-    m = current_mesh()
-    return tuple(getattr(m, "axis_names", ()) or ()) if m is not None else ()
+    return tuple(jax.sharding.get_abstract_mesh().axis_names)
 
 
 def _filter(spec_entry, axes):
@@ -96,13 +81,7 @@ def axis_size(name: str) -> int:
     m = current_mesh()
     if m is None:
         return 1
-    try:
-        return dict(zip(m.axis_names, m.axis_sizes))[name]
-    except (AttributeError, KeyError):
-        try:
-            return m.shape[name]
-        except Exception:
-            return 1
+    return dict(zip(m.axis_names, m.axis_sizes)).get(name, 1)
 
 
 DATA_AXES = ("pod", "data")

@@ -1,7 +1,7 @@
 """Host-side page allocator for the paged KV cache (DESIGN §14).
 
 The device side is dumb on purpose: per attention layer a
-(n_pages, page_size, KV, hd) pool plus a (n_slots, max_pages) int32 page
+(n_pages, KV, page_size, hd) pool plus a (n_slots, max_pages) int32 page
 table passed into every jitted decode step.  All ownership bookkeeping —
 which physical pages a slot holds, which are free — lives here on the host,
 where it costs a few list ops per admitted/evicted request instead of a

@@ -10,8 +10,9 @@ not leak into the rest of the suite):
     flat-buffer and per-leaf variants, across a full schedule period;
   * both == the einsum realization of ``schedule.step_matrix`` (allclose —
     the einsum contracts in a different summation order);
-  * the compiled HLO issues exactly K x rounds_per_step collective-permutes
-    per step (one per non-padded neighbor slot, none for padding).
+  * the compiled HLO issues one collective-permute per distinct live
+    neighbor permutation per round (none for padding; slots that repeat a
+    permutation, like a 2-row torus's up/down, share one).
 """
 import json
 import os
@@ -29,17 +30,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
+from repro.analysis.targets import live_slots
 from repro.core.dpsgd import (mix_einsum, mix_ppermute_schedule,
                               mix_ppermute_schedule_flat)
 from repro.core.schedule import DETERMINISTIC_TOPOLOGIES, make_schedule
+from repro.launch.mesh import make_mesh
 
 n = 8
-mesh = jax.make_mesh((n,), ("learners",))
+mesh = make_mesh((n,), ("learners",))
 tree = {"a": jax.random.normal(jax.random.PRNGKey(0), (n, 4, 2)),
         "b": jax.random.normal(jax.random.PRNGKey(1), (n, 5))}
 specs = jax.tree_util.tree_map(lambda _: P("learners"), tree)
@@ -74,12 +72,12 @@ for name in DETERMINISTIC_TOPOLOGIES:
     variants = max(2, s.period if s.time_varying else 1)
     for step in range(variants + 1):        # cross the period boundary too
         st = jnp.int32(step)
-        with mesh:
-            got_flat = _shard_map(
+        with jax.set_mesh(mesh):
+            got_flat = jax.shard_map(
                 lambda p: mix_ppermute_schedule_flat(p, ("learners",), st, s),
                 mesh=mesh, in_specs=(specs,), out_specs=specs,
-                check_rep=False)(tree)
-            got_leaf = _shard_map(
+                check_vma=False)(tree)
+            got_leaf = jax.shard_map(
                 lambda p: mix_ppermute_schedule(p, ("learners",), st, s),
                 mesh=mesh, in_specs=(specs,), out_specs=specs)(tree)
         ref = gather_ref(tree, s, step)
@@ -93,23 +91,19 @@ for name in DETERMINISTIC_TOPOLOGIES:
                 res["max_err_vs_einsum"],
                 float(np.max(np.abs(np.asarray(got_flat[k], np.float64)
                                     - np.asarray(ein[k], np.float64)))))
-    # collective count: one permute per non-padded neighbor slot per round
-    with mesh:
-        lowered = jax.jit(lambda p: _shard_map(
+    # collective count: one permute per distinct live permutation per round
+    with jax.set_mesh(mesh):
+        lowered = jax.jit(lambda p: jax.shard_map(
             lambda q: mix_ppermute_schedule_flat(
                 q, ("learners",), jnp.int32(0), s),
             mesh=mesh, in_specs=(specs,), out_specs=specs,
-            check_rep=False)(p)).lower(tree).compile()
+            check_vma=False)(p)).lower(tree).compile()
     res["collective_permutes"] = len(re.findall(
         r"collective-permute(?:-start)?\(", lowered.as_text()))
-    live_slots = int(sum(
-        0 if ((s.partners[r, k] == np.arange(n)).all()
-              and not s.coefs[r][:, 1 + k].any()) else 1
-        for r in range(s.period) for k in range(s.K)))
     # a static step runs every period round; one_peer_exp runs exactly one
     # round per step but compiles all period branches (lax.switch): XLA
-    # keeps one collective per branch, so the count stays == live slots
-    res["expected_permutes"] = live_slots
+    # keeps one collective per branch, so the count stays == live perms
+    res["expected_permutes"] = live_slots(s)
     res["K"] = s.K
     out[name] = res
 print(json.dumps(out))
@@ -151,7 +145,8 @@ def test_ppermute_matches_einsum_matrix(results, name):
 @pytest.mark.parametrize("name", TOPOLOGIES)
 def test_collective_count_is_one_permute_per_neighbor_slot(results, name):
     """The flat variant issues exactly one collective-permute per live
-    neighbor slot — padding slots cost nothing, and leaf count does not
-    multiply the collectives."""
+    neighbor slot — padding slots cost nothing, slots with the same
+    permutation share one, and leaf count does not multiply the
+    collectives."""
     r = results[name]
     assert r["collective_permutes"] == r["expected_permutes"], r

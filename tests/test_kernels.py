@@ -57,8 +57,8 @@ def _paged_operands(S, hd, H, KV, page, max_pages, lengths, seed=0):
     ks = jax.random.split(key, 3)
     n_pages = 1 + S * max_pages            # page 0 = scratch, never mapped
     q = jax.random.normal(ks[0], (S, H, hd))
-    kp = jax.random.normal(ks[1], (n_pages, page, KV, hd))
-    vp = jax.random.normal(ks[2], (n_pages, page, KV, hd))
+    kp = jax.random.normal(ks[1], (n_pages, KV, page, hd))
+    vp = jax.random.normal(ks[2], (n_pages, KV, page, hd))
     rng = np.random.default_rng(seed)
     table = jnp.asarray(rng.permutation(np.arange(1, n_pages))
                         .reshape(S, max_pages), jnp.int32)

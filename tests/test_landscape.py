@@ -234,10 +234,11 @@ def test_ssgd_autolr_beats_ssgd_on_launch_path():
     through set_controller_scale."""
     from types import SimpleNamespace
 
+    from repro.launch.mesh import make_test_mesh
     from repro.launch.train import (PjitTrainState, jit_train_step,
                                     make_probe_step, make_ssgd_train_step)
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh(1, 1)
     api = SimpleNamespace(loss_fn=quad_loss)
     batch = {"x": jnp.zeros((2, 1))}        # (GB, ...) with L=1 learner
     init = {"w": jnp.ones((D,))}
@@ -255,7 +256,7 @@ def test_ssgd_autolr_beats_ssgd_on_launch_path():
                                opt_state=optimizer.init(init_run),
                                step=jnp.zeros((), jnp.int32),
                                rng=jax.random.PRNGKey(0))
-        with mesh:
+        with jax.set_mesh(mesh):
             for i in range(steps):
                 if with_autolr and i % 10 == 0:
                     r = probe_fn(state.params, batch,

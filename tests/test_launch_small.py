@@ -24,7 +24,8 @@ from repro.launch.train import (jit_train_step, make_adpsgd_train_step,
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_test_mesh
+mesh = make_test_mesh(4, 2)
 cfg = get_config("transformer-100m").smoke_config()
 api = build_model(cfg)
 opt = sgd(0.1, momentum=0.9)
@@ -42,7 +43,7 @@ for algo, backend in [("dpsgd", "einsum"), ("dpsgd", "ppermute"),
                                       slow_learner=0, slow_factor=3)
     else:
         step = make_ssgd_train_step(api, opt, mesh)
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jit_train_step(
             step, in_shardings=shd.named_shardings((shds, bshd), mesh),
             out_shardings=shd.named_shardings((shds, None), mesh),
@@ -67,7 +68,7 @@ for algo, backend in [("dpsgd", "einsum"), ("dpsgd", "ppermute"),
             age=jnp.zeros((4,), jnp.int32))
         batch = jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), bspecs)
-        with mesh:
+        with jax.set_mesh(mesh):
             run = jit_train_step(step)
             ages = []
             for _ in range(4):
@@ -106,7 +107,7 @@ bspecs = api.train_batch_spec(8, 64)
 batch = jax.tree_util.tree_map(lambda s: jnp.zeros(s.shape, s.dtype), bspecs)
 leaf0 = lambda p: jax.tree_util.tree_leaves(p)[0]
 dead_row, frozen, n_act, losses_fin = None, False, [], []
-with mesh:
+with jax.set_mesh(mesh):
     erun = jit_train_step(estep)
     for i in range(8):
         drop = apply_plan(mem, plan, i)
@@ -134,7 +135,7 @@ cache_specs = jax.eval_shape(lambda: api.init_cache(None, 8, 64))
 cache_shd = shd.cache_sharding(cache_specs, mesh)
 tok = jax.ShapeDtypeStruct((8, 1), jnp.int32)
 tok_shd = shd.batch_sharding(tok, mesh, stacked=False)
-with mesh:
+with jax.set_mesh(mesh):
     c = jax.jit(make_decode_step(api),
                 in_shardings=shd.named_shardings(
                     (params_shd, cache_shd, tok_shd, P()), mesh),

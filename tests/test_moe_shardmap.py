@@ -17,7 +17,8 @@ from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.models.moe import init_moe_params, moe_forward
 from repro.models.moe_shardmap import moe_forward_shardmap
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_test_mesh
+mesh = make_test_mesh(2, 4)
 E, k, d, f = 8, 2, 16, 32
 B, S = 4, 8
 key = jax.random.PRNGKey(0)
@@ -25,14 +26,14 @@ params = init_moe_params(key, d, f, E, jnp.float32)
 x = jax.random.normal(jax.random.PRNGKey(1), (B, S, d))
 
 # big capacity so neither backend drops -> outputs must match exactly
-with mesh:
+with jax.set_mesh(mesh):
     y_sm = jax.jit(lambda p, xx: moe_forward_shardmap(
         p, xx, n_experts=E, top_k=k, capacity_factor=64.0))(params, x)
 y_ref = moe_forward(params, x, n_experts=E, top_k=k, capacity_factor=64.0)
 err = float(jnp.abs(y_sm - y_ref).max())
 
 # gradient path
-with mesh:
+with jax.set_mesh(mesh):
     g = jax.jit(jax.grad(lambda p, xx: jnp.sum(moe_forward_shardmap(
         p, xx, n_experts=E, top_k=k, capacity_factor=64.0) ** 2)))(params, x)
 gnorm = float(sum(jnp.abs(l).sum() for l in jax.tree_util.tree_leaves(g)))

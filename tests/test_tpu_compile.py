@@ -1,0 +1,111 @@
+"""Main-path Pallas kernels compile for a described TPU v5e chip.
+
+Interpret mode and the jnp oracles cannot see Mosaic's block-shape rules
+(last two block dims divisible by (8, 128) or equal to the array's), so
+each kernel of the training and serving hot paths is compiled here at
+transformer-100m shapes against a described ``v5e:2x2`` topology — no chip
+is attached; nothing runs.  The topology is described inside a fixture,
+never at import or collection: only one process at a time may load the
+TPU compiler library, and the workers of a parallel test run all import
+this file.  The persistent compile cache is off around these compiles (an
+entry written for a described device cannot be read back without one).
+"""
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from repro.configs import get_config  # noqa: E402
+from repro.core.flatstate import flat_meta  # noqa: E402
+from repro.kernels.decode_attention import \
+    paged_decode_attention_fwd  # noqa: E402
+from repro.kernels.flash_attention import flash_attention_fwd  # noqa: E402
+from repro.kernels.gossip_mix import LANE, gossip_mix_update_flat  # noqa
+from repro.models.model import build_model  # noqa: E402
+
+N_LEARNERS = 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:           # no TPU compiler library here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return get_config("transformer-100m")
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("K,publish", [(1, False), (2, False), (1, True)],
+                         ids=["K1", "K2", "publish"])
+def test_gossip_mix_update_flat_compiles(one_chip, cfg, K, publish):
+    """The flat engine's fused gossip+SGD step at the learner store of
+    transformer-100m (n=4, T=1,056,920 rows of 128): pair matching, ring,
+    and the AD-PSGD publish mode."""
+    T = flat_meta(jax.eval_shape(build_model(cfg).init,
+                                 jax.random.PRNGKey(0))).rows
+    assert T == 1_056_920
+    n = N_LEARNERS
+    big = _spec((n, T, LANE), jnp.float32, one_chip)
+    partners = _spec((K, n), jnp.int32, one_chip)
+    coefs = _spec((n, K + (5 if publish else 3)), jnp.float32, one_chip)
+    buffer = big if publish else None
+    compiled = jax.jit(
+        lambda w, g, mu, p, c, *buf: gossip_mix_update_flat(
+            w, w, g, mu, p, c, lr=0.1, beta=0.9,
+            buffer=buf[0] if buf else None)
+    ).lower(big, big, big, partners, coefs,
+            *((buffer,) if publish else ())).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_paged_decode_attention_compiles(one_chip, cfg, dtype):
+    """Serving decode over the head-major paged pool at transformer-100m's
+    heads (KV=12, hd=64): 4 slots, 16-token pages, 8 pages per slot."""
+    S, page, max_pages = 4, 16, 8
+    KV, hd, H = cfg.n_kv_heads, cfg.head_dim_, cfg.n_heads
+    assert (KV, hd) == (12, 64)
+    P = 1 + S * max_pages
+    q = _spec((S, H, hd), dtype, one_chip)
+    pool = _spec((P, KV, page, hd), dtype, one_chip)
+    table = _spec((S, max_pages), jnp.int32, one_chip)
+    lengths = _spec((S,), jnp.int32, one_chip)
+    compiled = jax.jit(paged_decode_attention_fwd).lower(
+        q, pool, pool, table, lengths).compile()
+    _assert_kernel(compiled)
+
+
+def test_flash_attention_fwd_compiles(one_chip, cfg):
+    """Training/prefill attention at seq 512 with transformer-100m heads."""
+    B, S = 2, 512
+    q = _spec((B, cfg.n_heads, S, cfg.head_dim_), jnp.float32, one_chip)
+    kv = _spec((B, cfg.n_kv_heads, S, cfg.head_dim_), jnp.float32, one_chip)
+    compiled = jax.jit(flash_attention_fwd).lower(q, kv, kv).compile()
+    _assert_kernel(compiled)
