@@ -40,6 +40,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..obs import phase
 from ..optim import Optimizer, apply_updates
 from . import schedule as gsched
 from . import topology as topo
@@ -279,14 +280,28 @@ class MultiLearnerTrainer:
         return TrainState(stacked, opt_state, jnp.zeros((), jnp.int32), key,
                           buffer=buffer, age=age, clock=clock)
 
+    @phase("fwd_bwd")
+    def _fwd_bwd(self, params, stacked_batch, in_axes=0):
+        """Per-learner (losses, gradients), on the flat store where the
+        engine keeps one."""
+        loss_fn = self._loss_flat if self._flat else self.loss_fn
+        return jax.vmap(jax.value_and_grad(loss_fn),
+                        in_axes=in_axes)(params, stacked_batch)
+
     # -- optimizer call (decentlam-aware) -------------------------------------
+    @phase("update")
     def _opt_update(self, grads, opt_state, params, mixed):
         if getattr(self.optimizer, "wants_mixed", False):
             return jax.vmap(self.optimizer.update)(grads, opt_state, params,
                                                    mixed)
         return jax.vmap(self.optimizer.update)(grads, opt_state, params)
 
+    @phase("update")
+    def _apply(self, params, updates):
+        return apply_updates(params, updates)
+
     # -- flat-engine pieces ---------------------------------------------------
+    @phase("gossip")
     def _fused_step(self, w, remote, grads, opt_state, partners, coefs,
                     active=None, buffer=None, nbr_fresh=None, publish=None,
                     weight_decay=None):
@@ -301,7 +316,8 @@ class MultiLearnerTrainer:
         ``weight_decay`` overrides the optimizer's static recipe (the
         multi-round path passes 0 after folding the decay of the PRE-mix
         weights into the gradients — the kernel only sees the mixed w).
-        Returns (w_new, opt_state[, buffer_new]).
+        Returns (w_new, opt_state[, buffer_new]).  It runs under the
+        ``gossip`` phase: the kernel mixes and updates in one pass.
         """
         from ..kernels import ops as kops
         f = self._fused
@@ -329,6 +345,7 @@ class MultiLearnerTrainer:
             return w_new, opt_state, out[2]
         return w_new, opt_state
 
+    @phase("gossip")
     def _select_nonflat(self, mask, new, old):
         """Per-learner select skipping (T, 128) leaves the kernel already
         selected in-pass (the momentum buffer)."""
@@ -342,6 +359,7 @@ class MultiLearnerTrainer:
             return jnp.where(m, a, b)
         return jax.tree_util.tree_map(_sel, new, old)
 
+    @phase("gossip")
     def _mix_sched(self, stacked, key, step):
         """Schedule-driven gossip for the UNFUSED paths (pytree engine and
         the flat engine's generic-optimizer fallback) — works on stacked
@@ -396,6 +414,7 @@ class MultiLearnerTrainer:
             members=self.membership_state(membership,
                                           drop_round=drop_round))
 
+    @phase("gossip")
     def _member_rounds(self, mem: MemberState, key, step):
         """The elastic analogue of ``schedule.step_rounds``: per-round
         (partners (K, n), coefs (n, K+1)) tables for this step, built from
@@ -435,6 +454,7 @@ class MultiLearnerTrainer:
             out.append((p, jnp.where(mem.drop_round, id_c, c)))
         return out
 
+    @phase("gossip")
     def _mix_member_rounds(self, stacked, rounds, active):
         """Unfused elastic mixing: apply ``_member_rounds`` tables to a
         stacked tree / flat buffer.  Randomized matchings keep the O(P)
@@ -471,45 +491,51 @@ class MultiLearnerTrainer:
         key = jax.random.fold_in(state.rng, state.step)
         k_mix, k_noise = jax.random.split(key)
 
-        grad_fn = jax.value_and_grad(self.loss_fn)
         zero = jnp.zeros((), jnp.float32)
         stale_mean, stale_max = zero, zero
         buffer, age, clock = state.buffer, state.age, state.clock
 
         if algo.algo == "ssgd":
-            w_a = learner_mean(state.params)
-            losses, grads = jax.vmap(grad_fn, in_axes=(None, 0))(w_a, stacked_batch)
-            g_mean = learner_mean(grads)
-            # identical update on every learner keeps copies in sync
-            g_stacked = jax.tree_util.tree_map(
-                lambda g: jnp.broadcast_to(g[None], (algo.n_learners,) + g.shape),
-                g_mean)
+            with phase("gossip"):
+                w_a = learner_mean(state.params)
+            losses, grads = self._fwd_bwd(w_a, stacked_batch,
+                                          in_axes=(None, 0))
+            with phase("gossip"):
+                g_mean = learner_mean(grads)
+                # identical update on every learner keeps copies in sync
+                g_stacked = jax.tree_util.tree_map(
+                    lambda g: jnp.broadcast_to(g[None], (algo.n_learners,)
+                                               + g.shape), g_mean)
             updates, opt_state = self._opt_update(
                 g_stacked, state.opt_state, state.params, state.params)
-            new_params = apply_updates(state.params, updates)
-            new_params = mean_broadcast(new_params)
+            new_params = self._apply(state.params, updates)
+            with phase("gossip"):
+                new_params = mean_broadcast(new_params)
 
         elif algo.algo == "ssgd_star":
-            w_a = learner_mean(state.params)
+            with phase("gossip"):
+                w_a = learner_mean(state.params)
             noisy = perturb_weights(
                 k_noise,
                 jax.tree_util.tree_map(
                     lambda p: jnp.broadcast_to(p[None],
                                                (algo.n_learners,) + p.shape), w_a),
                 algo.noise_std)
-            losses, grads = jax.vmap(grad_fn)(noisy, stacked_batch)
-            g_mean = learner_mean(grads)
-            g_stacked = jax.tree_util.tree_map(
-                lambda g: jnp.broadcast_to(g[None], (algo.n_learners,) + g.shape),
-                g_mean)
+            losses, grads = self._fwd_bwd(noisy, stacked_batch)
+            with phase("gossip"):
+                g_mean = learner_mean(grads)
+                g_stacked = jax.tree_util.tree_map(
+                    lambda g: jnp.broadcast_to(g[None], (algo.n_learners,)
+                                               + g.shape), g_mean)
             updates, opt_state = self._opt_update(
                 g_stacked, state.opt_state, state.params, state.params)
-            new_params = apply_updates(state.params, updates)
-            new_params = mean_broadcast(new_params)
+            new_params = self._apply(state.params, updates)
+            with phase("gossip"):
+                new_params = mean_broadcast(new_params)
 
         elif algo.algo == "dpsgd":
             # gradients at LOCAL weights (the whole point of the paper)
-            losses, grads = jax.vmap(grad_fn)(state.params, stacked_batch)
+            losses, grads = self._fwd_bwd(state.params, stacked_batch)
             mem = state.members
             if mem is not None:       # elastic fleet (DESIGN §15)
                 act = mem.active
@@ -519,15 +545,16 @@ class MultiLearnerTrainer:
                     mixed = self._mix_member_rounds(state.params, rounds, act)
                     updates, opt_new = self._opt_update(
                         grads, state.opt_state, state.params, mixed)
-                    stepped = apply_updates(mixed, updates)
+                    stepped = self._apply(mixed, updates)
                 else:
                     updates, opt_new = self._opt_update(
                         grads, state.opt_state, state.params, state.params)
                     stepped = self._mix_member_rounds(
-                        apply_updates(state.params, updates), rounds, act)
+                        self._apply(state.params, updates), rounds, act)
                 # dead learners' quarantined rows stay bitwise frozen
-                new_params = _select(act, stepped, state.params)
-                opt_state = _select(act, opt_new, state.opt_state)
+                with phase("update"):
+                    new_params = _select(act, stepped, state.params)
+                    opt_state = _select(act, opt_new, state.opt_state)
             elif algo.gossip_order == "mix_then_descend":   # paper Eq. 2
                 # _mix_sched keeps the gather form for random matchings
                 # (O(P), and the reference AD-PSGD reduces to it at
@@ -536,12 +563,12 @@ class MultiLearnerTrainer:
                 mixed = self._mix_sched(state.params, k_mix, state.step)
                 updates, opt_state = self._opt_update(
                     grads, state.opt_state, state.params, mixed)
-                new_params = apply_updates(mixed, updates)
+                new_params = self._apply(mixed, updates)
             else:                                          # descend_then_mix
                 updates, opt_state = self._opt_update(
                     grads, state.opt_state, state.params, state.params)
                 new_params = self._mix_sched(
-                    apply_updates(state.params, updates), k_mix, state.step)
+                    self._apply(state.params, updates), k_mix, state.step)
 
         elif algo.algo == "adpsgd":
             # Async pairwise gossip, simulated one global tick at a time:
@@ -567,54 +594,58 @@ class MultiLearnerTrainer:
                 fresh = age >= algo.max_staleness   # forced publish (tau)
                 stale_seen = jnp.where(fresh, 0, age)
                 partner = pair_partners(k_mix, n)
-            remote = _select(fresh, state.params, buffer)
             stale_mean = jnp.mean(stale_seen.astype(jnp.float32))
             stale_max = jnp.max(stale_seen).astype(jnp.float32)
 
-            losses, grads = jax.vmap(grad_fn)(state.params, stacked_batch)
-            mixed = mix_pair_gather(state.params, partner, remote)
+            losses, grads = self._fwd_bwd(state.params, stacked_batch)
+            with phase("gossip"):
+                remote = _select(fresh, state.params, buffer)
+                mixed = mix_pair_gather(state.params, partner, remote)
             updates, opt_state_new = self._opt_update(
                 grads, state.opt_state, state.params, mixed)
-            stepped = apply_updates(mixed, updates)
+            stepped = self._apply(mixed, updates)
 
             # inactive learners are mid-step: weights and momentum unchanged
-            new_params = _select(active, stepped, state.params)
-            opt_state = _select(active, opt_state_new, state.opt_state)
+            with phase("update"):
+                new_params = _select(active, stepped, state.params)
+                opt_state = _select(active, opt_state_new, state.opt_state)
             # publishing: completing a step publishes the new weights; a
             # forced-fresh learner re-publishes its (unchanged) in-progress
             # weights — both cases read off new_params
-            buffer = _select(active | fresh, new_params, buffer)
+            with phase("gossip"):
+                buffer = _select(active | fresh, new_params, buffer)
             age = jnp.where(active | fresh, 0, age + 1)
             clock = clock + active.astype(jnp.int32)
         else:
             raise ValueError(algo.algo)
 
         mem = state.members
-        gsq = _per_learner_grad_sq(grads)
-        if mem is None:
-            nact = jnp.float32(algo.n_learners)
-            loss = jnp.mean(losses)
-            g_mean = learner_mean(grads)
-            gsq_mean = jnp.mean(gsq)
-            sigma = learner_var(new_params)
-        else:        # active-only statistics: evicted rows are bitwise-absent
-            act = mem.active
-            nact = jnp.maximum(jnp.sum(act), 1).astype(jnp.float32)
-            loss = jnp.sum(jnp.where(act, losses, 0.0)) / nact
-            g_mean = masked_learner_mean(grads, act)
-            gsq_mean = jnp.sum(jnp.where(act, gsq, 0.0)) / nact
-            sigma = masked_learner_var(new_params, act)
-        metrics = StepMetrics(
-            loss=loss,
-            grad_norm=jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                                   for g in jax.tree_util.tree_leaves(
-                                       g_mean))),
-            sigma_w_sq=sigma,
-            staleness_mean=stale_mean,
-            staleness_max=stale_max,
-            n_active=nact,
-            grad_sq_mean=gsq_mean,
-        )
+        with phase("stats"):
+            gsq = _per_learner_grad_sq(grads)
+            if mem is None:
+                nact = jnp.float32(algo.n_learners)
+                loss = jnp.mean(losses)
+                g_mean = learner_mean(grads)
+                gsq_mean = jnp.mean(gsq)
+                sigma = learner_var(new_params)
+            else:    # active-only statistics: evicted rows are bitwise-absent
+                act = mem.active
+                nact = jnp.maximum(jnp.sum(act), 1).astype(jnp.float32)
+                loss = jnp.sum(jnp.where(act, losses, 0.0)) / nact
+                g_mean = masked_learner_mean(grads, act)
+                gsq_mean = jnp.sum(jnp.where(act, gsq, 0.0)) / nact
+                sigma = masked_learner_var(new_params, act)
+            metrics = StepMetrics(
+                loss=loss,
+                grad_norm=jnp.sqrt(sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree_util.tree_leaves(g_mean))),
+                sigma_w_sq=sigma,
+                staleness_mean=stale_mean,
+                staleness_max=stale_max,
+                n_active=nact,
+                grad_sq_mean=gsq_mean,
+            )
         return TrainState(new_params, opt_state, state.step + 1, state.rng,
                           buffer=buffer, age=age, clock=clock,
                           members=state.members), metrics
@@ -632,27 +663,29 @@ class MultiLearnerTrainer:
         key = jax.random.fold_in(state.rng, state.step)
         k_mix, _ = jax.random.split(key)
 
-        grad_fn = jax.value_and_grad(self._loss_flat)
         zero = jnp.zeros((), jnp.float32)
         stale_mean, stale_max = zero, zero
         buffer, age, clock = state.buffer, state.age, state.clock
         w = state.params
 
         if algo.algo == "ssgd":
-            w_a = jnp.mean(w, axis=0)
-            losses, grads = jax.vmap(grad_fn, in_axes=(None, 0))(w_a,
-                                                                 stacked_batch)
-            g_mean = jnp.mean(grads, axis=0)
-            g_stacked = jnp.broadcast_to(g_mean[None], w.shape)
+            with phase("gossip"):
+                w_a = jnp.mean(w, axis=0)
+            losses, grads = self._fwd_bwd(w_a, stacked_batch,
+                                          in_axes=(None, 0))
+            with phase("gossip"):
+                g_mean = jnp.mean(grads, axis=0)
+                g_stacked = jnp.broadcast_to(g_mean[None], w.shape)
             updates, opt_state = self._opt_update(g_stacked, state.opt_state,
                                                   w, w)
-            new_params = apply_updates(w, updates)
-            new_params = jnp.broadcast_to(jnp.mean(new_params, axis=0)[None],
-                                          w.shape)
+            new_params = self._apply(w, updates)
+            with phase("gossip"):
+                new_params = jnp.broadcast_to(
+                    jnp.mean(new_params, axis=0)[None], w.shape)
 
         elif algo.algo == "dpsgd":
             mem = state.members
-            losses, grads = jax.vmap(grad_fn)(w, stacked_batch)
+            losses, grads = self._fwd_bwd(w, stacked_batch)
             if self._fused is not None:
                 # the compiled schedule's per-step rounds: leading rounds
                 # run as mixing-only kernel passes (multi-round schedules —
@@ -662,9 +695,10 @@ class MultiLearnerTrainer:
                 # the kernel's active column (dead rows stay bitwise put).
                 from ..kernels import ops as kops
                 act = None if mem is None else mem.active
-                rounds = (self._schedule.step_rounds(k_mix, state.step)
-                          if mem is None
-                          else self._member_rounds(mem, k_mix, state.step))
+                with phase("gossip"):
+                    rounds = (self._schedule.step_rounds(k_mix, state.step)
+                              if mem is None else
+                              self._member_rounds(mem, k_mix, state.step))
                 g_upd, wd = grads, None
                 if len(rounds) > 1 and self._fused.weight_decay:
                     # weight decay regularizes the PRE-mix local weights
@@ -673,11 +707,14 @@ class MultiLearnerTrainer:
                     # buffer instead — fold it into the gradients here and
                     # zero the kernel's own decay term (grads itself stays
                     # raw: the grad_norm metric reads it below)
-                    g_upd = grads + self._fused.weight_decay * w
+                    with phase("update"):
+                        g_upd = grads + self._fused.weight_decay * w
                     wd = 0.0
-                for partners, coefs in rounds[:-1]:
-                    w = kops.flat_gossip_mix(w, partners, coefs, active=act,
-                                             backend=self.kernel_backend)
+                with phase("gossip"):
+                    for partners, coefs in rounds[:-1]:
+                        w = kops.flat_gossip_mix(w, partners, coefs,
+                                                 active=act,
+                                                 backend=self.kernel_backend)
                 partners, coefs = rounds[-1]
                 new_params, opt_state_new = self._fused_step(
                     w, w, g_upd, state.opt_state, partners, coefs,
@@ -693,23 +730,24 @@ class MultiLearnerTrainer:
                     mixed = self._mix_member_rounds(w, rounds, act)
                     updates, opt_state_new = self._opt_update(
                         grads, state.opt_state, w, mixed)
-                    stepped = apply_updates(mixed, updates)
+                    stepped = self._apply(mixed, updates)
                 else:                                   # descend_then_mix
                     updates, opt_state_new = self._opt_update(
                         grads, state.opt_state, w, w)
                     stepped = self._mix_member_rounds(
-                        apply_updates(w, updates), rounds, act)
-                new_params = jnp.where(act[:, None, None], stepped, w)
-                opt_state = _select(act, opt_state_new, state.opt_state)
+                        self._apply(w, updates), rounds, act)
+                with phase("update"):
+                    new_params = jnp.where(act[:, None, None], stepped, w)
+                    opt_state = _select(act, opt_state_new, state.opt_state)
             elif algo.gossip_order == "mix_then_descend":
                 mixed = self._mix_sched(w, k_mix, state.step)
                 updates, opt_state = self._opt_update(grads, state.opt_state,
                                                       w, mixed)
-                new_params = apply_updates(mixed, updates)
+                new_params = self._apply(mixed, updates)
             else:                                       # descend_then_mix
                 updates, opt_state = self._opt_update(grads, state.opt_state,
                                                       w, w)
-                new_params = self._mix_sched(apply_updates(w, updates),
+                new_params = self._mix_sched(self._apply(w, updates),
                                              k_mix, state.step)
 
         elif algo.algo == "adpsgd":
@@ -731,7 +769,7 @@ class MultiLearnerTrainer:
             stale_mean = jnp.mean(stale_seen.astype(jnp.float32))
             stale_max = jnp.max(stale_seen).astype(jnp.float32)
 
-            losses, grads = jax.vmap(grad_fn)(w, stacked_batch)
+            losses, grads = self._fwd_bwd(w, stacked_batch)
             if self._fused is not None:
                 # the matching + solo-aware coefs come from the compiled
                 # schedule — ONE source of truth with the DPSGD fused path
@@ -739,12 +777,11 @@ class MultiLearnerTrainer:
                 # bitwise sync==async(tau=0) contract is table-for-table).
                 # Elastic fleets draw the only-active matching from the
                 # membership mask instead.
-                if mem is None:
-                    (partners, coefs), = self._schedule.step_rounds(
-                        k_mix, state.step)
-                else:
-                    (partners, coefs), = self._member_rounds(mem, k_mix,
-                                                             state.step)
+                with phase("gossip"):
+                    (partners, coefs), = (
+                        self._schedule.step_rounds(k_mix, state.step)
+                        if mem is None else
+                        self._member_rounds(mem, k_mix, state.step))
                 partner = partners[0]
                 # publish-mode kernel: stale-remote select, straggler select
                 # AND the published-buffer rewrite all happen in the one
@@ -762,15 +799,19 @@ class MultiLearnerTrainer:
                 else:
                     partner = topo.masked_pair_partners(
                         k_mix, mem.active, drop=mem.drop_round)
-                remote = jnp.where(fresh[:, None, None], w, buffer)
-                mixed = mix_pair_gather(w, partner, remote)
+                with phase("gossip"):
+                    remote = jnp.where(fresh[:, None, None], w, buffer)
+                    mixed = mix_pair_gather(w, partner, remote)
                 updates, opt_state_new = self._opt_update(
                     grads, state.opt_state, w, mixed)
-                stepped = apply_updates(mixed, updates)
-                new_params = jnp.where(active[:, None, None], stepped, w)
-                opt_state = _select(active, opt_state_new, state.opt_state)
-                buffer = jnp.where((active | fresh)[:, None, None],
-                                   new_params, buffer)
+                stepped = self._apply(mixed, updates)
+                with phase("update"):
+                    new_params = jnp.where(active[:, None, None], stepped, w)
+                    opt_state = _select(active, opt_state_new,
+                                        state.opt_state)
+                with phase("gossip"):
+                    buffer = jnp.where((active | fresh)[:, None, None],
+                                       new_params, buffer)
             age = jnp.where(active | fresh, 0, age + 1)
             clock = clock + active.astype(jnp.int32)
         else:
@@ -782,33 +823,35 @@ class MultiLearnerTrainer:
         # as the per-leaf learner_var (pads contribute exactly 0) at about
         # half jnp.var's cost, and numerically safe at consensus (the
         # E[x^2]-E[x]^2 shortcut is NOT — it cancels catastrophically there)
-        gsq = jnp.sum(jnp.square(grads), axis=(1, 2))
-        if mem is None:
-            nact = jnp.float32(n)
-            loss = jnp.mean(losses)
-            g_mean = jnp.mean(grads, axis=0)
-            gsq_mean = jnp.mean(gsq)
-            dev = new_params - jnp.mean(new_params, axis=0)
-            sigma = jnp.sum(jnp.square(dev)) / n
-        else:        # active-only statistics: quarantined rows are excluded
-            act = mem.active
-            nact = jnp.maximum(jnp.sum(act), 1).astype(jnp.float32)
-            m3 = act[:, None, None]
-            loss = jnp.sum(jnp.where(act, losses, 0.0)) / nact
-            g_mean = jnp.sum(jnp.where(m3, grads, 0.0), axis=0) / nact
-            gsq_mean = jnp.sum(jnp.where(act, gsq, 0.0)) / nact
-            w_mean = jnp.sum(jnp.where(m3, new_params, 0.0), axis=0) / nact
-            dev = jnp.where(m3, new_params - w_mean[None], 0.0)
-            sigma = jnp.sum(jnp.square(dev)) / nact
-        metrics = StepMetrics(
-            loss=loss,
-            grad_norm=jnp.sqrt(jnp.sum(jnp.square(g_mean))),
-            sigma_w_sq=sigma,
-            staleness_mean=stale_mean,
-            staleness_max=stale_max,
-            n_active=nact,
-            grad_sq_mean=gsq_mean,
-        )
+        with phase("stats"):
+            gsq = jnp.sum(jnp.square(grads), axis=(1, 2))
+            if mem is None:
+                nact = jnp.float32(n)
+                loss = jnp.mean(losses)
+                g_mean = jnp.mean(grads, axis=0)
+                gsq_mean = jnp.mean(gsq)
+                dev = new_params - jnp.mean(new_params, axis=0)
+                sigma = jnp.sum(jnp.square(dev)) / n
+            else:    # active-only statistics: quarantined rows are excluded
+                act = mem.active
+                nact = jnp.maximum(jnp.sum(act), 1).astype(jnp.float32)
+                m3 = act[:, None, None]
+                loss = jnp.sum(jnp.where(act, losses, 0.0)) / nact
+                g_mean = jnp.sum(jnp.where(m3, grads, 0.0), axis=0) / nact
+                gsq_mean = jnp.sum(jnp.where(act, gsq, 0.0)) / nact
+                w_mean = jnp.sum(jnp.where(m3, new_params, 0.0),
+                                 axis=0) / nact
+                dev = jnp.where(m3, new_params - w_mean[None], 0.0)
+                sigma = jnp.sum(jnp.square(dev)) / nact
+            metrics = StepMetrics(
+                loss=loss,
+                grad_norm=jnp.sqrt(jnp.sum(jnp.square(g_mean))),
+                sigma_w_sq=sigma,
+                staleness_mean=stale_mean,
+                staleness_max=stale_max,
+                n_active=nact,
+                grad_sq_mean=gsq_mean,
+            )
         return TrainState(new_params, opt_state, state.step + 1, state.rng,
                           buffer=buffer, age=age, clock=clock,
                           members=state.members), metrics

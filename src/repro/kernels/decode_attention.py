@@ -98,6 +98,7 @@ def paged_decode_attention_fwd(q, k_pages, v_pages, page_table, lengths, *,
 
     out = pl.pallas_call(
         kern,
+        name="paged_decode_attention_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=grid,
             in_specs=[

@@ -95,6 +95,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
 
     return pl.pallas_call(
         kern,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, bq, hd),

@@ -73,6 +73,7 @@ def gossip_mix_update(w, neighbors, grads, momentum, coefs, *, lr: float,
 
     return pl.pallas_call(
         kern,
+        name="gossip_mix_update",
         grid=grid,
         in_specs=[block, nbr_block, block, block, coef_block],
         out_specs=[block, block],
@@ -255,6 +256,7 @@ def gossip_mix_update_flat(w, remote, grads, momentum, partners, coefs, *,
 
     out = pl.pallas_call(
         kern,
+        name="gossip_mix_update_flat",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid,
             in_specs=in_specs, out_specs=out_specs),

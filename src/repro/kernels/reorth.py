@@ -89,6 +89,7 @@ def reorth_dots(basis, w, *, interpret: bool = False,
     kern = functools.partial(_dots_kernel, n_vecs=M)
     lanes = pl.pallas_call(
         kern,
+        name="reorth_dots",
         grid=grid,
         in_specs=[pl.BlockSpec((M, rows, LANE), lambda i: (0, i, 0)),
                   pl.BlockSpec((rows, LANE), lambda i: (i, 0))],
@@ -117,6 +118,7 @@ def reorth_axpy(w, basis, dots, *, interpret: bool = False,
     block = pl.BlockSpec((rows, LANE), lambda i: (i, 0))
     out = pl.pallas_call(
         kern,
+        name="reorth_axpy",
         grid=grid,
         in_specs=[block,
                   pl.BlockSpec((M, rows, LANE), lambda i: (0, i, 0)),

@@ -35,6 +35,7 @@ from ..core.dpsgd import (member_active_mask, mix_einsum, mix_ppermute_pair,
 from ..core.schedule import make_schedule
 from ..models.model import ModelAPI
 from ..models.shard_hints import activation_batch_axes
+from ..obs import phase
 from ..optim import Optimizer, apply_updates
 from . import sharding as shd
 from .mesh import learner_axes, n_learners
@@ -144,21 +145,24 @@ def make_dpsgd_train_step(api: ModelAPI, optimizer: Optimizer, mesh,
         # spmd_axis_name: in-model activation constraints (residual_hint)
         # see the learner dim sharded over the learner mesh axes; the
         # per-learner batch itself is unsharded -> batch axes context ()
-        with activation_batch_axes(()):
+        with activation_batch_axes(()), phase("fwd_bwd"):
             losses, grads = jax.vmap(jax.value_and_grad(api.loss_fn),
                                      in_axes=(0, 0),
                                      spmd_axis_name=l_axes)(
                 state.params, stacked_batch)
         key = jax.random.fold_in(state.rng, state.step)
-        mixed = gossip(state.params, key, state.step)  # paper Eq. 2 ordering
-        if getattr(optimizer, "wants_mixed", False):   # decentlam correction
-            updates, opt_state = jax.vmap(optimizer.update)(
-                grads, state.opt_state, state.params, mixed)
-        else:
-            updates, opt_state = jax.vmap(optimizer.update)(
-                grads, state.opt_state, state.params)
-        new_params = apply_updates(mixed, updates)
-        metrics = {"loss": jnp.mean(losses)}
+        with phase("gossip"):                          # paper Eq. 2 ordering
+            mixed = gossip(state.params, key, state.step)
+        with phase("update"):
+            if getattr(optimizer, "wants_mixed", False):  # decentlam
+                updates, opt_state = jax.vmap(optimizer.update)(
+                    grads, state.opt_state, state.params, mixed)
+            else:
+                updates, opt_state = jax.vmap(optimizer.update)(
+                    grads, state.opt_state, state.params)
+            new_params = apply_updates(mixed, updates)
+        with phase("stats"):
+            metrics = {"loss": jnp.mean(losses)}
         return PjitTrainState(new_params, opt_state, state.step + 1,
                               state.rng), metrics
 
@@ -237,7 +241,7 @@ def make_adpsgd_train_step(api: ModelAPI, optimizer: Optimizer, mesh, *,
     def train_step(state: PjitTrainState, batch):
         stacked_batch = jax.tree_util.tree_map(
             lambda x: x.reshape((L, x.shape[0] // L) + x.shape[1:]), batch)
-        with activation_batch_axes(()):
+        with activation_batch_axes(()), phase("fwd_bwd"):
             losses, grads = jax.vmap(jax.value_and_grad(api.loss_fn),
                                      in_axes=(0, 0),
                                      spmd_axis_name=l_axes)(
@@ -246,44 +250,51 @@ def make_adpsgd_train_step(api: ModelAPI, optimizer: Optimizer, mesh, *,
             live = state.active
             active = member_active_mask(state.step, live, state.slow_every)
             fresh = (state.age >= max_staleness) & live
-            mixed = gossip(state.params, state.buffer, state.age, state.step,
-                           live, state.drop_round)
+            with phase("gossip"):
+                mixed = gossip(state.params, state.buffer, state.age,
+                               state.step, live, state.drop_round)
         else:
             active = straggler_active_mask(state.step, L, slow_learner,
                                            slow_factor)
             fresh = state.age >= max_staleness
-            mixed = gossip(state.params, state.buffer, state.age, state.step,
-                           None, None)
-        if getattr(optimizer, "wants_mixed", False):   # decentlam correction
-            updates, opt_state_new = jax.vmap(optimizer.update)(
-                grads, state.opt_state, state.params, mixed)
-        else:
-            updates, opt_state_new = jax.vmap(optimizer.update)(
-                grads, state.opt_state, state.params)
-        stepped = apply_updates(mixed, updates)
+            with phase("gossip"):
+                mixed = gossip(state.params, state.buffer, state.age,
+                               state.step, None, None)
 
         def sel(mask):
             return lambda a, b: jnp.where(
                 mask.reshape((-1,) + (1,) * (a.ndim - 1)), a, b)
-        new_params = jax.tree_util.tree_map(sel(active), stepped, state.params)
-        opt_state = jax.tree_util.tree_map(sel(active), opt_state_new,
-                                           state.opt_state)
+        with phase("update"):
+            if getattr(optimizer, "wants_mixed", False):  # decentlam
+                updates, opt_state_new = jax.vmap(optimizer.update)(
+                    grads, state.opt_state, state.params, mixed)
+            else:
+                updates, opt_state_new = jax.vmap(optimizer.update)(
+                    grads, state.opt_state, state.params)
+            stepped = apply_updates(mixed, updates)
+            new_params = jax.tree_util.tree_map(sel(active), stepped,
+                                                state.params)
+            opt_state = jax.tree_util.tree_map(sel(active), opt_state_new,
+                                               state.opt_state)
         # active learners publish their new weights; forced-fresh inactive
         # ones re-publish their (unchanged) in-progress weights — both read
         # off new_params
-        buffer = jax.tree_util.tree_map(sel(active | fresh), new_params,
-                                        state.buffer)
+        with phase("gossip"):
+            buffer = jax.tree_util.tree_map(sel(active | fresh), new_params,
+                                            state.buffer)
         age = jnp.where(active | fresh, 0, state.age + 1)
-        if elastic:
-            nact = jnp.maximum(jnp.sum(state.active), 1).astype(jnp.float32)
-            loss = jnp.sum(jnp.where(state.active, losses, 0.0)) / nact
-            metrics = {"loss": loss, "n_active": nact,
-                       "staleness_max": jnp.max(jnp.where(
-                           fresh | ~state.active, 0, state.age))}
-        else:
-            metrics = {"loss": jnp.mean(losses),
-                       "staleness_max": jnp.max(jnp.where(fresh, 0,
-                                                          state.age))}
+        with phase("stats"):
+            if elastic:
+                nact = jnp.maximum(jnp.sum(state.active),
+                                   1).astype(jnp.float32)
+                loss = jnp.sum(jnp.where(state.active, losses, 0.0)) / nact
+                metrics = {"loss": loss, "n_active": nact,
+                           "staleness_max": jnp.max(jnp.where(
+                               fresh | ~state.active, 0, state.age))}
+            else:
+                metrics = {"loss": jnp.mean(losses),
+                           "staleness_max": jnp.max(jnp.where(fresh, 0,
+                                                              state.age))}
         return PjitTrainState(new_params, opt_state, state.step + 1,
                               state.rng, buffer=buffer, age=age,
                               active=state.active,
@@ -299,10 +310,15 @@ def make_adpsgd_train_step(api: ModelAPI, optimizer: Optimizer, mesh, *,
 
 def make_ssgd_train_step(api: ModelAPI, optimizer: Optimizer, mesh) -> Callable:
     def train_step(state: PjitTrainState, batch):
-        loss, grads = jax.value_and_grad(api.loss_fn)(state.params, batch)
-        updates, opt_state = optimizer.update(grads, state.opt_state,
-                                              state.params)
-        new_params = apply_updates(state.params, updates)
+        # the gradient all-reduce is XLA's, inserted inside the backward
+        # pass by the sharding: it has no gossip scope of its own
+        with phase("fwd_bwd"):
+            loss, grads = jax.value_and_grad(api.loss_fn)(state.params,
+                                                          batch)
+        with phase("update"):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  state.params)
+            new_params = apply_updates(state.params, updates)
         return PjitTrainState(new_params, opt_state, state.step + 1,
                               state.rng), {"loss": loss}
 

@@ -4,7 +4,8 @@ Interpret mode and the jnp oracles cannot see Mosaic's block-shape rules
 (last two block dims divisible by (8, 128) or equal to the array's), so
 each kernel of the training and serving hot paths is compiled here at
 transformer-100m shapes against a described ``v5e:2x2`` topology — no chip
-is attached; nothing runs.  The topology is described inside a fixture,
+is attached; nothing runs.  Each compiled call must carry its kernel's
+``name=``, the op name a profiler trace shows.  The topology is described inside a fixture,
 never at import or collection: only one process at a time may load the
 TPU compiler library, and the workers of a parallel test run all import
 this file.  The persistent compile cache is off around these compiles (an
@@ -24,17 +25,18 @@ from repro.core.flatstate import flat_meta  # noqa: E402
 from repro.kernels.decode_attention import \
     paged_decode_attention_fwd  # noqa: E402
 from repro.kernels.flash_attention import flash_attention_fwd  # noqa: E402
-from repro.kernels.gossip_mix import LANE, gossip_mix_update_flat  # noqa
+from repro.kernels.gossip_mix import (LANE, gossip_mix_update,  # noqa
+                                     gossip_mix_update_flat)
+from repro.kernels.reorth import reorth_axpy, reorth_dots  # noqa: E402
 from repro.models.model import build_model  # noqa: E402
 
 N_LEARNERS = 4
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -43,9 +45,15 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 @pytest.fixture(scope="module")
@@ -57,8 +65,12 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, name):
+    """The kernel compiled to a Mosaic call that carries its stable name
+    (the trace's op name, which the benchmark's traffic files match)."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"%{name}." in text, name
 
 
 @pytest.mark.parametrize("K,publish", [(1, False), (2, False), (1, True)],
@@ -81,7 +93,7 @@ def test_gossip_mix_update_flat_compiles(one_chip, cfg, K, publish):
             buffer=buf[0] if buf else None)
     ).lower(big, big, big, partners, coefs,
             *((buffer,) if publish else ())).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "gossip_mix_update_flat")
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -99,7 +111,7 @@ def test_paged_decode_attention_compiles(one_chip, cfg, dtype):
     lengths = _spec((S,), jnp.int32, one_chip)
     compiled = jax.jit(paged_decode_attention_fwd).lower(
         q, pool, pool, table, lengths).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "paged_decode_attention_fwd")
 
 
 def test_flash_attention_fwd_compiles(one_chip, cfg):
@@ -108,4 +120,75 @@ def test_flash_attention_fwd_compiles(one_chip, cfg):
     q = _spec((B, cfg.n_heads, S, cfg.head_dim_), jnp.float32, one_chip)
     kv = _spec((B, cfg.n_kv_heads, S, cfg.head_dim_), jnp.float32, one_chip)
     compiled = jax.jit(flash_attention_fwd).lower(q, kv, kv).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "flash_attention_fwd")
+
+
+def test_gossip_mix_update_compiles(one_chip):
+    """The single-learner gossip + momentum kernel, pair gossip (K=1)."""
+    T = 4096
+    one = _spec((T, LANE), jnp.float32, one_chip)
+    compiled = jax.jit(
+        lambda w, nbr, g, mu, c: gossip_mix_update(w, nbr, g, mu, c, lr=0.1)
+    ).lower(one, _spec((1, T, LANE), jnp.float32, one_chip), one, one,
+            _spec((2,), jnp.float32, one_chip)).compile()
+    _assert_kernel(compiled, "gossip_mix_update")
+
+
+@pytest.mark.parametrize("kernel", ["reorth_dots", "reorth_axpy"])
+def test_reorth_compiles(one_chip, kernel):
+    """The landscape probe's Lanczos reorthogonalization passes over an
+    8-vector basis."""
+    M, T = 8, 4096
+    basis = _spec((M, T, LANE), jnp.float32, one_chip)
+    w = _spec((T, LANE), jnp.float32, one_chip)
+    if kernel == "reorth_dots":
+        lowered = jax.jit(reorth_dots).lower(basis, w)
+    else:
+        lowered = jax.jit(reorth_axpy).lower(
+            w, basis, _spec((M,), jnp.float32, one_chip))
+    _assert_kernel(lowered.compile(), kernel)
+
+
+def test_launch_ring_step_phases_on_four_chips(v5e_2x2):
+    """The launch DPSGD step with ring gossip by collective-permute,
+    compiled for the four described chips (ordered as a 2x2 host's ring)
+    at test size: every matrix product (a ``convolution`` on the TPU) is
+    ``fwd_bwd``, the permutes are ``gossip``, and all four phases appear."""
+    from hlo_text import instructions
+    from hlo_text import phases as hlo_phases
+
+    from repro import obs
+    from repro.launch import sharding as shd
+    from repro.launch.mesh import make_mesh
+    from repro.launch.train import (jit_train_step, make_dpsgd_train_step,
+                                    train_state_shardings, train_state_specs)
+    from repro.optim import sgd
+    d = v5e_2x2
+    mesh = make_mesh((4, 1), ("data", "model"),
+                     devices=[d[0], d[1], d[3], d[2]])
+    api = build_model(get_config("transformer-100m").smoke_config())
+    opt = sgd(0.1, momentum=0.9)
+    step = make_dpsgd_train_step(api, opt, mesh, topology="ring",
+                                 gossip_backend="ppermute")
+    specs = train_state_specs(api, opt, mesh, algo="dpsgd")
+    shds = shd.named_shardings(
+        train_state_shardings(specs, mesh, algo="dpsgd"), mesh)
+    bspecs = api.train_batch_spec(8, 32)
+    bshd = shd.named_shardings(
+        shd.batch_sharding(bspecs, mesh, stacked=False), mesh)
+
+    def placed(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, s: _spec(a.shape, a.dtype, s), tree, shardings)
+    with jax.set_mesh(mesh):
+        text = jit_train_step(
+            step, in_shardings=(shds, bshd), out_shardings=(shds, None)
+        ).lower(placed(specs, shds), placed(bspecs, bshd)).compile(
+        ).as_text()
+    phases = hlo_phases(text)
+    ops = instructions(text)
+    convs = {phases.get(n) for n, op in ops if op == "convolution"}
+    permutes = {phases.get(n) for n, op in ops
+                if op.startswith("collective-permute")}
+    assert convs == {"fwd_bwd"} and permutes == {"gossip"}
+    assert set(phases.values()) == set(obs.PHASES)
