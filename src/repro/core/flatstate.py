@@ -22,8 +22,9 @@ This module makes the flat view the *source of truth* instead:
     whole train step free of parameter-sized concatenates (asserted by
     ``max_concat_elems`` in tests).
 
-Padding: T is rounded up to a multiple of ``ROW_ALIGN`` (f32 sublane tile)
-so any divisor-of-T block size is legal for the kernels.  The pad region is
+Padding: T is rounded up to a multiple of ``ROW_ALIGN`` (f32 sublane tile),
+so the store is whole (8, 128) tiles; the kernels' blocks need not divide
+T (a partial last block is masked).  The pad region is
 written as zeros at flatten time and never escapes: unflatten drops it, and
 gradients through unflatten are identically zero there.
 """
@@ -40,7 +41,7 @@ import numpy as np
 __all__ = ["LANE", "ROW_ALIGN", "FlatMeta", "flat_meta", "max_concat_elems"]
 
 LANE = 128
-ROW_ALIGN = 8           # f32 sublane tile: keeps every divisor-of-T block legal
+ROW_ALIGN = 8           # f32 sublane tile: the store is whole (8, 128) tiles
 
 
 @dataclasses.dataclass(frozen=True)

@@ -9,10 +9,19 @@ The DPSGD inner loop per learner i is
 
 Unfused, XLA emits three separate HBM-bound passes over the full parameter
 vector (mix read/write, momentum read/write, apply read/write) ≈ 8P moves.
-The fused kernel streams each (8,128)-aligned block of {w_self, w_neighbors,
-g, mu} through VMEM once and writes {w_new, mu_new}: ≈ (3+k)P moves, a
-~2.2x HBM-traffic cut on the op that IS the paper's technique (arithmetic
-intensity < 1 flop/byte — pure bandwidth).
+The fused kernel streams each block of {w_self, w_neighbors, g, mu} through
+VMEM once and writes {w_new, mu_new}: ≈ (3+k)P moves, a ~2.2x HBM-traffic
+cut on the op that IS the paper's technique (arithmetic intensity < 1
+flop/byte — pure bandwidth).
+
+Tiling: a block is (rows, 128) f32 rows of the store, as many as fit a
+fixed VMEM budget once every stream is double-buffered (``block_rows_for``);
+the grid is ``cdiv(T, rows)`` blocks, and Pallas masks the out-of-bounds
+rows of the partial last one.  The per-element arithmetic has no
+cross-row term, so the block size never changes a result.  Large blocks
+matter because each grid step has a fixed cost that small blocks cannot
+hide behind their DMA: transformer-100m's T = 2^3 * 5 * 26,423 rows admit
+no 8-aligned divisor between 40 and T, so a divisor-of-T block is 40 rows.
 
 Layout: the parameter pytree is flattened to a (T, 128) f32 view (padded);
 neighbor copies arrive as (K, T, 128) — on a real pod these are the
@@ -34,12 +43,36 @@ from jax.experimental.pallas import tpu as pltpu
 from ..core.flatstate import flat_meta
 
 LANE = 128
-BLOCK_ROWS = 256          # (256, 128) f32 block = 128 KiB / buffer in VMEM
+MAX_BLOCK_ROWS = 2048       # (2048, 128) f32 block = 1 MiB per buffer in VMEM
+VMEM_BUDGET = 12 * 2**20    # all streams' double buffers, under the 16 MiB
+                            # of scoped VMEM Mosaic allows a kernel by default
+
+
+def block_rows_for(T: int, streams: int,
+                   cap: int = MAX_BLOCK_ROWS) -> int:
+    """Rows of one (rows, 128) f32 block for a kernel that double-buffers
+    ``streams`` parameter-sized operands and outputs: the largest multiple
+    of 8 whose ``streams x 2`` buffers fit ``VMEM_BUDGET``, at most ``cap``
+    and at most T rounded up to 8.  The grid takes ``cdiv(T, rows)``
+    blocks; a partial last block is masked."""
+    fit = VMEM_BUDGET // (streams * 2 * LANE * 4)
+    rows = min(fit, cap, -(-T // 8) * 8)
+    return max(8, rows - rows % 8)
+
+
+def flat_block_rows(T: int, K: int, *, has_momentum: bool, publish: bool,
+                    cap: int = MAX_BLOCK_ROWS) -> int:
+    """``block_rows_for`` the batched kernel.  Its streams: w, K neighbors,
+    the partner's published buffer (publish), g, mu (has_momentum), the
+    learner's own buffer (publish); out w, mu (has_momentum), buffer
+    (publish)."""
+    streams = 3 + K + 2 * has_momentum + 3 * publish
+    return block_rows_for(T, streams, cap)
 
 
 def _kernel(w_ref, nbr_ref, g_ref, mu_ref, coef_ref, w_out_ref, mu_out_ref,
             *, n_neighbors: int, lr: float, beta: float):
-    """One (BLOCK_ROWS, LANE) tile.
+    """One (rows, LANE) tile.
 
     coef_ref: (1 + K,) f32 in SMEM — [self_coef, neighbor coefs...].
     """
@@ -56,15 +89,17 @@ def _kernel(w_ref, nbr_ref, g_ref, mu_ref, coef_ref, w_out_ref, mu_out_ref,
                    static_argnames=("lr", "beta", "interpret", "block_rows"))
 def gossip_mix_update(w, neighbors, grads, momentum, coefs, *, lr: float,
                       beta: float = 0.9, interpret: bool = False,
-                      block_rows: int = BLOCK_ROWS):
+                      block_rows: int = MAX_BLOCK_ROWS):
     """w, grads, momentum: (T, 128) f32; neighbors: (K, T, 128);
     coefs: (1 + K,) f32 mixing weights (self first).  Returns (w_new, mu_new).
+    ``block_rows`` caps the block ``block_rows_for`` picks.
     """
     T, lane = w.shape
     assert lane == LANE, lane
     K = neighbors.shape[0]
-    rows = _pick_rows(T, block_rows)
-    grid = (T // rows,)
+    # streams: w, K neighbors, g, mu; w_new, mu_new
+    rows = block_rows_for(T, 5 + K, block_rows)
+    grid = (pl.cdiv(T, rows),)
 
     kern = functools.partial(_kernel, n_neighbors=K, lr=lr, beta=beta)
     block = pl.BlockSpec((rows, LANE), lambda i: (i, 0))
@@ -87,7 +122,8 @@ def gossip_mix_update(w, neighbors, grads, momentum, coefs, *, lr: float,
 # batched (learner-major) kernel: the flat-engine hot path
 # ---------------------------------------------------------------------------
 #
-# One pallas_call updates ALL n learners: grid (n, T // rows), learner-major.
+# One pallas_call updates ALL n learners: grid (n, cdiv(T, rows)),
+# learner-major.
 # The K neighbor operands are not gathered on the host — the partner indices
 # ride in as scalar-prefetch operands and the neighbor BlockSpec index_map
 # reads its learner row straight out of the published/remote buffer
@@ -157,20 +193,6 @@ def _flat_kernel(part_ref, *refs, n_neighbors: int, lr: float, beta: float,
         buf_out[0] = jnp.where(coef_ref[i, 4 + k] > 0.5, new_w, buf_ref[0])
 
 
-def _pick_rows(T: int, block_rows: int) -> int:
-    """Largest sublane-aligned divisor of T that fits block_rows.
-
-    Flat-store T is always a multiple of 8 (flatstate.ROW_ALIGN), so an
-    8-aligned divisor exists (8 itself at worst); small ad-hoc T (tests,
-    tree wrapper) falls back to any divisor."""
-    r = min(block_rows, T)
-    while r > 8 and (T % r or r % 8):
-        r -= 1
-    while T % r:
-        r -= 1
-    return r
-
-
 @functools.partial(
     jax.jit, static_argnames=("lr", "beta", "weight_decay", "has_momentum",
                               "interpret", "block_rows"))
@@ -180,7 +202,7 @@ def gossip_mix_update_flat(w, remote, grads, momentum, partners, coefs, *,
                            has_momentum: bool = True,
                            buffer=None,
                            interpret: bool = False,
-                           block_rows: int = BLOCK_ROWS):
+                           block_rows: int = MAX_BLOCK_ROWS):
     """Batched fused gossip + SGD update on the persistent flat store.
 
     w, grads: (n, T, 128) f32 live weights / gradients.
@@ -205,6 +227,8 @@ def gossip_mix_update_flat(w, remote, grads, momentum, partners, coefs, *,
               ``where(nbr_fresh, remote[partner], buffer[partner])`` and a
               third output returns ``where(publish, w_new, buffer)`` — the
               whole async tick in one parameter pass.
+    block_rows: caps the block ``flat_block_rows`` picks from the call's
+              stream count.
     Returns (w_new, mu_new[, buffer_new]) — mu_new is ``momentum``
     untouched when ``has_momentum=False``; buffer_new only in publish mode.
     """
@@ -216,8 +240,9 @@ def gossip_mix_update_flat(w, remote, grads, momentum, partners, coefs, *,
     assert not publish or K == 1, "publish mode is pairwise (AD-PSGD)"
     assert partners.shape == (K, n), (partners.shape, n)
     assert coefs.shape == (n, ncoef), (coefs.shape, K, publish)
-    rows = _pick_rows(T, block_rows)
-    grid = (n, T // rows)
+    rows = flat_block_rows(T, K, has_momentum=has_momentum, publish=publish,
+                           cap=block_rows)
+    grid = (n, pl.cdiv(T, rows))
 
     block = pl.BlockSpec((1, rows, LANE), lambda i, j, p: (i, j, 0))
 

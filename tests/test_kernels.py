@@ -7,7 +7,8 @@ import pytest
 from repro.kernels import ref
 from repro.kernels.decode_attention import paged_decode_attention_fwd
 from repro.kernels.flash_attention import flash_attention_fwd
-from repro.kernels.gossip_mix import flatten_for_kernel, gossip_mix_update
+from repro.kernels.gossip_mix import (flatten_for_kernel, gossip_mix_update,
+                                     gossip_mix_update_flat)
 from repro.kernels.ops import (dpsgd_fused_update, flash_attention,
                                flat_gossip_update, paged_decode_attention)
 
@@ -256,6 +257,78 @@ def test_batched_kernel_solo_learner_keeps_self_mix():
                                backend="pallas")
     np.testing.assert_allclose(np.asarray(w1), np.asarray(w - 0.1 * g),
                                atol=1e-6)
+
+
+# T = 8 x 131: the only 8-aligned divisors are 8 and T itself, as
+# transformer-100m's store admits only 8 and 40 below T
+PRIME_T = 1048
+
+
+@pytest.mark.parametrize("K,publish,has_mu,inactive", [
+    (1, False, True, False), (2, False, True, False), (1, True, True, False),
+    (1, False, False, False), (2, False, True, True)],
+    ids=["K1", "K2", "publish", "no_momentum", "inactive"])
+def test_flat_kernel_partial_block_is_bitwise_divisor_tiling(
+        K, publish, has_mu, inactive):
+    """A block that leaves a partial last block (256 rows: 4 whole + 24)
+    gives outputs bitwise equal to a block that divides T (8 rows, the
+    tiling a divisor-of-T rule takes here), and both match the oracle."""
+    n, T = 4, PRIME_T
+    ks = jax.random.split(jax.random.PRNGKey(K + 2 * publish + 4 * has_mu), 5)
+    w = jax.random.normal(ks[0], (n, T, 128))
+    g = jax.random.normal(ks[1], (n, T, 128))
+    mu = jax.random.normal(ks[2], (n, T, 128))
+    buf = jax.random.normal(ks[3], (n, T, 128)) if publish else None
+    idx = jnp.arange(n)
+    partners = jnp.stack([(idx + s) % n
+                          for s in range(1, K + 1)]).astype(jnp.int32)
+    mix = jnp.full((n, K + 1), 1.0 / (K + 1))
+    scale = jnp.linspace(0.5, 1.5, n)[:, None]
+    active = jnp.ones((n,))
+    if inactive:
+        active = active.at[n - 1].set(0.0)
+    cols = [mix, scale, active[:, None]]
+    if publish:
+        fresh = jnp.zeros((n,)).at[1].set(1.0)
+        cols += [fresh[partners[0]][:, None], jnp.maximum(active, fresh)[:, None]]
+    coefs = jnp.concatenate(cols, axis=1).astype(jnp.float32)
+    kw = dict(lr=0.1, beta=0.9, weight_decay=0.01, has_momentum=has_mu,
+              buffer=buf)
+
+    outs = {rows: gossip_mix_update_flat(w, w, g, mu, partners, coefs,
+                                         interpret=True, block_rows=rows,
+                                         **kw)
+            for rows in (8, 256)}
+    expect = ref.gossip_mix_update_flat_ref(w, w, g, mu, partners, coefs,
+                                            **kw)
+    assert T % 8 == 0 and T % 256
+    for divides, partial, oracle in zip(outs[8], outs[256], expect):
+        np.testing.assert_array_equal(np.asarray(partial),
+                                      np.asarray(divides))
+        np.testing.assert_allclose(np.asarray(partial), np.asarray(oracle),
+                                   atol=1e-5)
+    if inactive:
+        np.testing.assert_array_equal(np.asarray(outs[256][0][n - 1]),
+                                      np.asarray(w[n - 1]))
+
+
+def test_gossip_kernel_partial_block_is_bitwise_divisor_tiling():
+    """The single-learner kernel under the same two tilings of T = 8 x 131."""
+    T, K = PRIME_T, 2
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    w = jax.random.normal(ks[0], (T, 128))
+    nb = jax.random.normal(ks[1], (K, T, 128))
+    g = jax.random.normal(ks[2], (T, 128))
+    mu = jax.random.normal(ks[3], (T, 128))
+    coefs = jnp.array([0.5, 0.25, 0.25], jnp.float32)
+    divides, partial = (gossip_mix_update(w, nb, g, mu, coefs, lr=0.1,
+                                          beta=0.9, interpret=True,
+                                          block_rows=rows)
+                        for rows in (8, 256))
+    expect = ref.gossip_mix_update_ref(w, nb, g, mu, coefs, lr=0.1, beta=0.9)
+    for a, b, c in zip(divides, partial, expect):
+        np.testing.assert_array_equal(np.asarray(b), np.asarray(a))
+        np.testing.assert_allclose(np.asarray(b), np.asarray(c), atol=1e-5)
 
 
 def test_flatten_roundtrip():

@@ -25,7 +25,8 @@ from repro.core.flatstate import flat_meta  # noqa: E402
 from repro.kernels.decode_attention import \
     paged_decode_attention_fwd  # noqa: E402
 from repro.kernels.flash_attention import flash_attention_fwd  # noqa: E402
-from repro.kernels.gossip_mix import (LANE, gossip_mix_update,  # noqa
+from repro.kernels.gossip_mix import (LANE, flat_block_rows,  # noqa
+                                     gossip_mix_update,
                                      gossip_mix_update_flat)
 from repro.kernels.reorth import reorth_axpy, reorth_dots  # noqa: E402
 from repro.models.model import build_model  # noqa: E402
@@ -78,10 +79,14 @@ def _assert_kernel(compiled, name):
 def test_gossip_mix_update_flat_compiles(one_chip, cfg, K, publish):
     """The flat engine's fused gossip+SGD step at the learner store of
     transformer-100m (n=4, T=1,056,920 rows of 128): pair matching, ring,
-    and the AD-PSGD publish mode."""
+    and the AD-PSGD publish mode.  T has no 8-aligned divisor between 40
+    and T, so the blocks leave a partial last one; they are large, and
+    their double buffers fit the scoped VMEM the compile allows."""
     T = flat_meta(jax.eval_shape(build_model(cfg).init,
                                  jax.random.PRNGKey(0))).rows
     assert T == 1_056_920
+    rows = flat_block_rows(T, K, has_momentum=True, publish=publish)
+    assert rows >= 1024 and rows % 8 == 0 and T % rows, rows
     n = N_LEARNERS
     big = _spec((n, T, LANE), jnp.float32, one_chip)
     partners = _spec((K, n), jnp.int32, one_chip)
