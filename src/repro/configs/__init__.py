@@ -5,6 +5,7 @@ from .granite_20b import CONFIG as GRANITE_20B
 from .granite_moe_3b_a800m import CONFIG as GRANITE_MOE_3B_A800M
 from .jamba_v01_52b import CONFIG as JAMBA_V01_52B
 from .mistral_large_123b import CONFIG as MISTRAL_LARGE_123B
+from .moonlight_16b_a3b import CONFIG as MOONLIGHT_16B_A3B
 from .qwen2_vl_7b import CONFIG as QWEN2_VL_7B
 from .qwen3_moe_235b_a22b import CONFIG as QWEN3_MOE_235B_A22B
 from .seamless_m4t_large_v2 import CONFIG as SEAMLESS_M4T_LARGE_V2
@@ -15,7 +16,7 @@ from .yi_34b import CONFIG as YI_34B
 REGISTRY = {c.name: c for c in [
     MISTRAL_LARGE_123B, SEAMLESS_M4T_LARGE_V2, GEMMA2_27B, GRANITE_20B,
     QWEN3_MOE_235B_A22B, XLSTM_350M, YI_34B, GRANITE_MOE_3B_A800M,
-    QWEN2_VL_7B, JAMBA_V01_52B, TRANSFORMER_100M,
+    QWEN2_VL_7B, JAMBA_V01_52B, TRANSFORMER_100M, MOONLIGHT_16B_A3B,
 ]}
 
 ASSIGNED = [c.name for c in [

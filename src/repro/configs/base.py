@@ -36,13 +36,24 @@ class ModelConfig:
     rope_theta: float = 1e4
     mrope_sections: Tuple[int, ...] = ()   # qwen2-vl M-RoPE (t, h, w)
     attn_chunk: int = 1024         # q/k chunking of the jnp reference path
+    attn_kind: str = "gqa"         # gqa | mla (latent attention, train only)
+    kv_lora_rank: int = 0          # mla: width of the shared latent c
+    q_lora_rank: int = 0           # mla: 0 = q is one plain projection
+    qk_nope_head_dim: int = 0      # mla: per-head q/k width without RoPE
+    qk_rope_head_dim: int = 0      # mla: RoPE width (one key for all heads)
+    v_head_dim: int = 0            # mla: per-head value width
 
     # --- MoE ------------------------------------------------------------------
     n_experts: int = 0
     experts_per_tok: int = 0
     moe_every: int = 1             # MoE FFN every k-th layer (jamba: 2)
-    capacity_factor: float = 1.25
-    moe_backend: str = "einsum"    # einsum | shard_map (explicit all-to-all)
+    first_dense_layers: int = 0    # leading dense layers before the periods
+    dense_d_ff: int = 0            # their SwiGLU width (0 -> d_ff)
+    shared_d_ff: int = 0           # shared experts, as one SwiGLU (0: none)
+    router_scoring: str = "softmax"  # softmax | sigmoid (+ selection bias)
+    routed_scaling: float = 1.0    # routed experts' weights scaled by this
+    norm_topk: bool = True         # renormalise the top-k weights to sum 1
+    experts_held: Tuple[int, ...] = ()   # (first, count) held here; () = all
 
     # --- SSM / xLSTM ----------------------------------------------------------
     block_period: Tuple[str, ...] = ()   # e.g. 8*('mamba',) with attn override
@@ -71,6 +82,12 @@ class ModelConfig:
     @property
     def head_dim_(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts whose weights this model
+        holds; every expert by default."""
+        return tuple(self.experts_held) or (0, self.n_experts)
 
     @property
     def padded_vocab(self) -> int:

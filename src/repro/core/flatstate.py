@@ -117,11 +117,18 @@ class FlatMeta:
     def _unflatten_impl(self, flat) -> Any:
         lead = self.leading(flat, flat=True)
         v = flat.reshape(lead + (self.padded,))
-        leaves = [
-            v[..., off:off + sz].reshape(lead + shape).astype(dtype)
-            for off, sz, shape, dtype in zip(self.offsets, self.sizes,
-                                             self.shapes, self.dtypes)
-        ]
+
+        def view(off, sz, shape, dtype):
+            part = v[..., off:off + sz]
+            if shape and shape[-1] % LANE:
+                # a leaf narrower than a lane row: without the barrier XLA
+                # may hoist its reshape above the slice and lay the WHOLE
+                # store out in the leaf's narrow, lane-padded tiling (2x
+                # the store, measured at a (4, 64) leaf)
+                part = jax.lax.optimization_barrier(part)
+            return part.reshape(lead + shape).astype(dtype)
+        leaves = [view(*m) for m in zip(self.offsets, self.sizes,
+                                         self.shapes, self.dtypes)]
         return jax.tree_util.tree_unflatten(self.treedef, leaves)
 
     def scatter(self, tree) -> jnp.ndarray:

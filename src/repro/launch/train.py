@@ -34,7 +34,7 @@ from ..core.dpsgd import (member_active_mask, mix_einsum, mix_ppermute_pair,
                           mix_ppermute_schedule_flat, straggler_active_mask)
 from ..core.schedule import make_schedule
 from ..models.model import ModelAPI
-from ..models.shard_hints import activation_batch_axes
+from ..models.shard_hints import activation_batch_axes, vmapped_learner_axes
 from ..obs import phase
 from ..optim import Optimizer, apply_updates
 from . import sharding as shd
@@ -95,7 +95,9 @@ def make_dpsgd_train_step(api: ModelAPI, optimizer: Optimizer, mesh,
     round, parity-pinned against the einsum step matrix; random matchings
     cannot be a compiled collective schedule, so they substitute the ring
     (the pre-schedule behavior — use the einsum backend for true random
-    pairing under pjit).  ``gossip_fuse``: 'flat' permutes each device's
+    pairing under pjit).  The step's metrics hold the mean loss and the
+    forward's counters (``api.loss_and_stats``), mean over learners.
+    ``gossip_fuse``: 'flat' permutes each device's
     LOCAL parameter shard as one lane-aligned (T_local, 128) buffer —
     collectives per step independent of leaf count (DESIGN §11); 'leaf' is
     the per-leaf reference collective schedule."""
@@ -145,10 +147,11 @@ def make_dpsgd_train_step(api: ModelAPI, optimizer: Optimizer, mesh,
         # spmd_axis_name: in-model activation constraints (residual_hint)
         # see the learner dim sharded over the learner mesh axes; the
         # per-learner batch itself is unsharded -> batch axes context ()
-        with activation_batch_axes(()), phase("fwd_bwd"):
-            losses, grads = jax.vmap(jax.value_and_grad(api.loss_fn),
-                                     in_axes=(0, 0),
-                                     spmd_axis_name=l_axes)(
+        with activation_batch_axes(()), vmapped_learner_axes(l_axes), \
+                phase("fwd_bwd"):
+            (losses, stats), grads = jax.vmap(
+                jax.value_and_grad(api.loss_and_stats, has_aux=True),
+                in_axes=(0, 0), spmd_axis_name=l_axes)(
                 state.params, stacked_batch)
         key = jax.random.fold_in(state.rng, state.step)
         with phase("gossip"):                          # paper Eq. 2 ordering
@@ -163,6 +166,10 @@ def make_dpsgd_train_step(api: ModelAPI, optimizer: Optimizer, mesh,
             new_params = apply_updates(mixed, updates)
         with phase("stats"):
             metrics = {"loss": jnp.mean(losses)}
+            # the forward's counters (MoE: held assignments), mean over
+            # learners; none for a dense model
+            metrics.update({k: jnp.mean(v.astype(jnp.float32))
+                            for k, v in stats.items()})
         return PjitTrainState(new_params, opt_state, state.step + 1,
                               state.rng), metrics
 
@@ -241,7 +248,8 @@ def make_adpsgd_train_step(api: ModelAPI, optimizer: Optimizer, mesh, *,
     def train_step(state: PjitTrainState, batch):
         stacked_batch = jax.tree_util.tree_map(
             lambda x: x.reshape((L, x.shape[0] // L) + x.shape[1:]), batch)
-        with activation_batch_axes(()), phase("fwd_bwd"):
+        with activation_batch_axes(()), vmapped_learner_axes(l_axes), \
+                phase("fwd_bwd"):
             losses, grads = jax.vmap(jax.value_and_grad(api.loss_fn),
                                      in_axes=(0, 0),
                                      spmd_axis_name=l_axes)(

@@ -44,13 +44,15 @@ def init_attn_params(key, d_model: int, n_heads: int, n_kv: int, head_dim: int,
 def chunked_attention(q, k, v, *, q_positions, k_positions, causal: bool = True,
                       window: int = 0, attn_softcap: float = 0.0,
                       chunk: int = 1024):
-    """q: (B, Sq, H, hd); k, v: (B, Sk, KV, hd); positions: (Sq,), (Sk,).
+    """q, k: (B, Sq|Sk, H|KV, hd); v: (B, Sk, KV, vd); positions: (Sq,),
+    (Sk,).  v may be narrower than q and k (latent attention).
 
-    Returns (B, Sq, H, hd).  Blocked over both q and k with an online
+    Returns (B, Sq, H, vd).  Blocked over both q and k with an online
     softmax; each q-block body is rematerialized in the backward pass.
     """
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape
+    vd = v.shape[-1]
     G = H // KV
     cq = min(chunk, Sq)
     ck = min(chunk, Sk)
@@ -60,7 +62,7 @@ def chunked_attention(q, k, v, *, q_positions, k_positions, causal: bool = True,
 
     qs = q.reshape(B, nq, cq, KV, G, hd).transpose(1, 0, 2, 3, 4, 5)
     ks = k.reshape(B, nk, ck, KV, hd).transpose(1, 0, 2, 3, 4)
-    vs = v.reshape(B, nk, ck, KV, hd).transpose(1, 0, 2, 3, 4)
+    vs = v.reshape(B, nk, ck, KV, vd).transpose(1, 0, 2, 3, 4)
     qp = q_positions.reshape(nq, cq)
     kp = k_positions.reshape(nk, ck)
 
@@ -69,7 +71,7 @@ def chunked_attention(q, k, v, *, q_positions, k_positions, causal: bool = True,
         # qb: (B, cq, KV, G, hd); qpb: (cq,)
         m0 = jnp.full((B, KV, G, cq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KV, G, cq), jnp.float32)
-        a0 = jnp.zeros((B, cq, KV, G, hd), jnp.float32)
+        a0 = jnp.zeros((B, cq, KV, G, vd), jnp.float32)
 
         def kv_step(carry, inp):
             m, l, acc = carry
@@ -94,14 +96,14 @@ def chunked_attention(q, k, v, *, q_positions, k_positions, causal: bool = True,
 
         (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), (ks, vs, kp))
         out = acc / jnp.maximum(l, 1e-30).transpose(0, 3, 1, 2)[..., None]
-        return out  # (B, cq, KV, G, hd)
+        return out  # (B, cq, KV, G, vd)
 
     def q_step(_, inp):
         qb, qpb = inp
         return None, one_q_block(qb, qpb)
 
     _, outs = jax.lax.scan(q_step, None, (qs, qp))
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, H, hd)
+    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, H, vd)
     return out.astype(q.dtype)
 
 

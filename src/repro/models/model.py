@@ -3,6 +3,10 @@
 API (all pure functions):
   init(key)                          -> params        (single learner, no stack)
   loss_fn(params, batch)             -> scalar        (one learner's minibatch)
+  loss_and_stats(params, batch)      -> (scalar, stats) stats: counters the
+                                        forward keeps; {"moe_held_assignments"}
+                                        (held experts' (token, expert) pairs,
+                                        summed over MoE layers) or {}
   apply(params, batch)               -> logits        (train/prefill forward)
   init_cache(params, batch, buf_len) -> decode cache
   decode_step(params, cache, tokens, pos) -> (logits, cache)
@@ -44,6 +48,7 @@ class ModelAPI(NamedTuple):
     paged_decode_step: Any = None
     reset_slot: Any = None
     has_paged: bool = False
+    loss_and_stats: Any = None
 
 
 def _mrope_positions(cfg: ModelConfig, P: int, S_text: int):
@@ -74,10 +79,14 @@ def build_model(cfg: ModelConfig) -> ModelAPI:
         def apply(params, batch):
             return transformer.apply(params, cfg, batch["tokens"])
 
-        def loss_fn(params, batch):
-            logits = apply(params, batch)
+        def loss_and_stats(params, batch):
+            logits, stats = transformer.apply(params, cfg, batch["tokens"],
+                                              return_stats=True)
             return cross_entropy(logits, batch["labels"], batch.get("mask"),
-                                 logical_vocab=cfg.vocab)
+                                 logical_vocab=cfg.vocab), stats
+
+        def loss_fn(params, batch):
+            return loss_and_stats(params, batch)[0]
 
         def init_cache(params, batch_size, buf_len):
             return transformer.init_cache(cfg, batch_size, buf_len)
@@ -101,12 +110,15 @@ def build_model(cfg: ModelConfig) -> ModelAPI:
                                                  positions, page_table,
                                                  advance)
 
+        decodes = not transformer.training_only(cfg)
         return ModelAPI(cfg=cfg, init=init, loss_fn=loss_fn, apply=apply,
                         init_cache=init_cache, decode_step=decode_step,
-                        train_batch_spec=train_batch_spec, has_decode=True,
+                        train_batch_spec=train_batch_spec,
+                        has_decode=decodes,
                         init_paged_cache=init_paged_cache,
                         paged_decode_step=paged_decode_step,
-                        reset_slot=transformer.reset_slot, has_paged=True)
+                        reset_slot=transformer.reset_slot, has_paged=decodes,
+                        loss_and_stats=loss_and_stats)
 
     # ---------------------------------------------------------------- VLM --
     elif cfg.family == "vlm":
@@ -176,7 +188,8 @@ def build_model(cfg: ModelConfig) -> ModelAPI:
     return ModelAPI(cfg=cfg, init=init, loss_fn=loss_fn, apply=apply,
                     init_cache=init_cache, decode_step=decode_step,
                     train_batch_spec=train_batch_spec,
-                    has_decode=True)
+                    has_decode=True,
+                    loss_and_stats=lambda p, b: (loss_fn(p, b), {}))
 
 
 def make_synthetic_batch(cfg: ModelConfig, key, global_batch: int, seq: int):

@@ -65,6 +65,25 @@ def activation_batch_axes(axes):
         _CTX.batch_axes = prev
 
 
+@contextlib.contextmanager
+def vmapped_learner_axes(axes):
+    """Inside it, a ``vmap`` over learners is sharded over the mesh axes
+    ``axes`` (the DPSGD steps' ``spmd_axis_name``): code that must run once
+    per learner, unbatched, maps over them with ``shard_map``."""
+    prev = getattr(_CTX, "learner_axes", ())
+    _CTX.learner_axes = tuple(axes)
+    try:
+        yield
+    finally:
+        _CTX.learner_axes = prev
+
+
+def learner_axes() -> tuple:
+    """The mesh axes the current vmap over learners is sharded over, or ()
+    (no mesh: the learners share one device)."""
+    return getattr(_CTX, "learner_axes", ())
+
+
 def residual_hint(x):
     """Constrain a (B, S, d) residual-stream activation: batch over the
     context's batch axes, S and d replicated over `model` — forces XLA's

@@ -6,8 +6,15 @@ with MoE every 2nd layer; xlstm: [mLSTM, sLSTM]; dense: [attn]).  The stack is
 a lax.scan over stacked period params, so the HLO is O(period), not O(depth)
 — essential for compiling 88-layer models on one CPU core in the dry-run.
 
-Layer spec = (mixer, mlp) with mixer in {attn, attn_local, mamba, mlstm,
-slstm} and mlp in {dense, moe, none}.
+Layer spec = (mixer, mlp) with mixer in {attn, attn_local, mla, mamba,
+mlstm, slstm} and mlp in {dense, moe, none}.
+
+``cfg.first_dense_layers`` leading layers (DeepSeek-V3's
+``first_k_dense_replace``) run before the periods as a scan of their own
+over ``params["lead"]`` (periods of one layer): the period's mixer with a dense SwiGLU of width
+``cfg.dense_d_ff``, remat per layer as the periods are.  Such a model, and
+one with latent attention (``mla``), is built for training only: it has no
+decode cache.
 """
 from __future__ import annotations
 
@@ -23,6 +30,7 @@ from .attention import (attn_decode, attn_decode_paged, attn_forward,
                         init_paged_attn_cache)
 from .layers import (apply_mrope, apply_rope, dense_init, dtype_of,
                      embed_init, rms_norm, softcap)
+from .mla import init_mla_params, mla_forward
 from .mamba import (init_mamba_cache, init_mamba_params, mamba_decode,
                     mamba_forward)
 from .moe import init_moe_params, moe_forward
@@ -51,6 +59,8 @@ def period_spec(cfg: ModelConfig) -> Tuple[Tuple[str, str], ...]:
             spec.append((mixer, mlp))
         return tuple(spec)
     mlp = "moe" if cfg.n_experts else "dense"
+    if cfg.attn_kind == "mla":
+        return (("mla", mlp),)
     if cfg.attn_pattern == "local_global":
         return (("attn_local", mlp), ("attn", mlp))
     if cfg.attn_pattern == "sliding":
@@ -60,8 +70,25 @@ def period_spec(cfg: ModelConfig) -> Tuple[Tuple[str, str], ...]:
 
 def n_periods(cfg: ModelConfig) -> int:
     p = len(period_spec(cfg))
-    assert cfg.n_layers % p == 0, (cfg.n_layers, p)
-    return cfg.n_layers // p
+    n = cfg.n_layers - cfg.first_dense_layers
+    assert n % p == 0, (n, p)
+    return n // p
+
+
+def lead_spec(cfg: ModelConfig) -> Tuple[str, str]:
+    """(mixer, mlp) of each leading dense layer."""
+    return (period_spec(cfg)[0][0], "dense")
+
+
+def training_only(cfg: ModelConfig) -> bool:
+    return cfg.attn_kind == "mla" or cfg.first_dense_layers > 0
+
+
+def _refuse_decode(cfg: ModelConfig):
+    if training_only(cfg):
+        raise ValueError(f"{cfg.name}: latent attention and leading dense "
+                         "layers are built for training only; there is no "
+                         "decode cache")
 
 
 # ---------------------------------------------------------------------------
@@ -81,14 +108,18 @@ def make_rope_fn(cfg: ModelConfig):
 # per-layer init
 # ---------------------------------------------------------------------------
 
-def _init_layer(key, cfg: ModelConfig, mixer: str, mlp: str):
+def _init_layer(key, cfg: ModelConfig, mixer: str, mlp: str,
+                d_ff: int = 0):
     dt = dtype_of(cfg.param_dtype)
     d = cfg.d_model
+    d_ff = d_ff or cfg.d_ff
     k1, k2, k3 = jax.random.split(key, 3)
     p = {"norm1": jnp.zeros((d,), jnp.float32)}
     if mixer in ("attn", "attn_local"):
         p["mixer"] = init_attn_params(k1, d, cfg.n_heads, cfg.n_kv_heads,
                                       cfg.head_dim_, dt)
+    elif mixer == "mla":
+        p["mixer"] = init_mla_params(k1, cfg, dt)
     elif mixer == "mamba":
         p["mixer"] = init_mamba_params(k1, d, expand=cfg.ssm_expand,
                                        state=cfg.ssm_state, conv=cfg.ssm_conv,
@@ -101,12 +132,15 @@ def _init_layer(key, cfg: ModelConfig, mixer: str, mlp: str):
         raise ValueError(mixer)
     if mlp == "dense":
         p["norm2"] = jnp.zeros((d,), jnp.float32)
-        p["mlp"] = {"w1": dense_init(k2, d, cfg.d_ff, dt),
-                    "w3": dense_init(k3, d, cfg.d_ff, dt),
-                    "w2": dense_init(jax.random.fold_in(k3, 1), cfg.d_ff, d, dt)}
+        p["mlp"] = {"w1": dense_init(k2, d, d_ff, dt),
+                    "w3": dense_init(k3, d, d_ff, dt),
+                    "w2": dense_init(jax.random.fold_in(k3, 1), d_ff, d, dt)}
     elif mlp == "moe":
         p["norm2"] = jnp.zeros((d,), jnp.float32)
-        p["mlp"] = init_moe_params(k2, d, cfg.d_ff, cfg.n_experts, dt)
+        p["mlp"] = init_moe_params(
+            k2, d, cfg.d_ff, cfg.n_experts, dt, held=cfg.held,
+            shared_d_ff=cfg.shared_d_ff,
+            select_bias=cfg.router_scoring == "sigmoid")
     return p
 
 
@@ -115,6 +149,7 @@ def init_params(key, cfg: ModelConfig):
     spec = period_spec(cfg)
     np_ = n_periods(cfg)
     k_embed, k_blocks, k_head = jax.random.split(key, 3)
+    k_lead = jax.random.fold_in(k_blocks, 1)
 
     def init_period(k):
         ks = jax.random.split(k, len(spec))
@@ -126,6 +161,12 @@ def init_params(key, cfg: ModelConfig):
         "periods": jax.vmap(init_period)(jax.random.split(k_blocks, np_)),
         "final_norm": jnp.zeros((cfg.d_model,), jnp.float32),
     }
+    if cfg.first_dense_layers:
+        mixer, mlp = lead_spec(cfg)
+        params["lead"] = jax.vmap(
+            lambda k: {"l0": _init_layer(k, cfg, mixer, mlp,
+                                         d_ff=cfg.dense_d_ff)})(
+            jax.random.split(k_lead, cfg.first_dense_layers))
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(k_head, cfg.d_model, cfg.padded_vocab, dt)
     return params
@@ -135,11 +176,24 @@ def init_params(key, cfg: ModelConfig):
 # forward
 # ---------------------------------------------------------------------------
 
+def _moe(mlp_params, h, cfg: ModelConfig, return_stats: bool = False):
+    return moe_forward(mlp_params, h, n_experts=cfg.n_experts,
+                       top_k=cfg.experts_per_tok, scoring=cfg.router_scoring,
+                       routed_scaling=cfg.routed_scaling,
+                       norm_topk=cfg.norm_topk, held=cfg.held,
+                       return_stats=return_stats)
+
+
 def _layer_forward(lp, x, cfg: ModelConfig, mixer: str, mlp: str, rope_fn,
                    positions):
+    """-> (x, held MoE assignments this layer computed (int32))."""
     from .shard_hints import residual_hint
     x = residual_hint(x)
-    if mixer in ("attn", "attn_local"):
+    held = jnp.zeros((), jnp.int32)
+    if mixer == "mla":
+        h = rms_norm(x, lp["norm1"], cfg.norm_eps)
+        x = x + mla_forward(lp["mixer"], h, cfg, positions)
+    elif mixer in ("attn", "attn_local"):
         h = rms_norm(x, lp["norm1"], cfg.norm_eps)
         win = cfg.window if (mixer == "attn_local"
                              or cfg.attn_pattern == "sliding") else 0
@@ -171,41 +225,47 @@ def _layer_forward(lp, x, cfg: ModelConfig, mixer: str, mlp: str, rope_fn,
         x = x + h
     elif mlp == "moe":
         h = rms_norm(residual_hint(x), lp["norm2"], cfg.norm_eps)
-        if cfg.moe_backend == "shard_map":
-            from .moe_shardmap import moe_forward_shardmap, shardmap_applicable
-            if shardmap_applicable(cfg.n_experts, h.shape[1]):
-                x = x + moe_forward_shardmap(
-                    lp["mlp"], h, n_experts=cfg.n_experts,
-                    top_k=cfg.experts_per_tok,
-                    capacity_factor=cfg.capacity_factor)
-                return x
-        x = x + moe_forward(lp["mlp"], h, n_experts=cfg.n_experts,
-                            top_k=cfg.experts_per_tok,
-                            capacity_factor=cfg.capacity_factor)
-    return x
+        y, held = _moe(lp["mlp"], h, cfg, return_stats=True)
+        x = x + y
+    return x, held
 
 
-def forward(params, cfg: ModelConfig, x, positions):
-    """x: (B, S, d) input embeddings; positions: (S,) or (3, S) for M-RoPE.
-    Returns final hidden states (B, S, d)."""
-    spec = period_spec(cfg)
-    rope_fn = make_rope_fn(cfg)
+def _scan_layers(x, stacked, spec, cfg: ModelConfig, rope_fn, positions):
+    """Scan the stacked per-period params over x; -> (x, held MoE
+    assignments summed over the layers)."""
+
+    has_moe = any(mlp == "moe" for _, mlp in spec)
 
     @jax.checkpoint
     def period_body(x, pp):
         # remat per period: the layer scan would otherwise stack every
         # intermediate activation of every period for the backward pass
         # (measured 96 GB -> ~x/period for xlstm-350m train_4k)
+        held = jnp.zeros((), jnp.int32)
         for i, (mixer, mlp) in enumerate(spec):
-            x = _layer_forward(pp[f"l{i}"], x, cfg, mixer, mlp, rope_fn,
-                               positions)
+            x, n = _layer_forward(pp[f"l{i}"], x, cfg, mixer, mlp, rope_fn,
+                                  positions)
+            held = held + n
+        return x, (held if has_moe else None)
+
+    x, held = jax.lax.scan(period_body, x, stacked)
+    return x, (jnp.sum(held) if has_moe else None)
+
+
+def forward(params, cfg: ModelConfig, x, positions,
+            return_stats: bool = False):
+    """x: (B, S, d) input embeddings; positions: (S,) or (3, S) for M-RoPE.
+    Returns final hidden states (B, S, d) [, {"moe_held_assignments"}
+    where the model has MoE layers]."""
+    rope_fn = make_rope_fn(cfg)
+    if cfg.first_dense_layers:
+        x, _ = _scan_layers(x, params["lead"], (lead_spec(cfg),), cfg,
+                            rope_fn, positions)
+    x, held = _scan_layers(x, params["periods"], period_spec(cfg), cfg,
+                           rope_fn, positions)
+    if not return_stats:
         return x
-
-    def period_fn(x, pp):
-        return period_body(x, pp), None
-
-    x, _ = jax.lax.scan(period_fn, x, params["periods"])
-    return x
+    return x, ({} if held is None else {"moe_held_assignments": held})
 
 
 def logits_from_hidden(params, cfg: ModelConfig, x):
@@ -219,8 +279,9 @@ def embed_tokens(params, cfg: ModelConfig, tokens):
     return params["embed"][tokens] * math.sqrt(cfg.d_model)
 
 
-def apply(params, cfg: ModelConfig, tokens, positions=None, extra_embeds=None):
-    """tokens: (B, S) -> logits (B, S_total, V).
+def apply(params, cfg: ModelConfig, tokens, positions=None, extra_embeds=None,
+          return_stats: bool = False):
+    """tokens: (B, S) -> logits (B, S_total, V) [, stats as ``forward``].
 
     extra_embeds: (B, P, d) frontend stub embeddings (audio frames / vision
     patches) prepended to the token embeddings (vlm / audio families).
@@ -234,8 +295,11 @@ def apply(params, cfg: ModelConfig, tokens, positions=None, extra_embeds=None):
             positions = jnp.broadcast_to(jnp.arange(S), (3, S))
         else:
             positions = jnp.arange(S)
-    h = forward(params, cfg, x, positions)
-    return logits_from_hidden(params, cfg, h)
+    if not return_stats:
+        return logits_from_hidden(params, cfg, forward(params, cfg, x,
+                                                       positions))
+    h, stats = forward(params, cfg, x, positions, return_stats=True)
+    return logits_from_hidden(params, cfg, h), stats
 
 
 # ---------------------------------------------------------------------------
@@ -259,6 +323,7 @@ def _layer_cache(cfg: ModelConfig, mixer: str, batch: int, buf_len: int):
 
 
 def init_cache(cfg: ModelConfig, batch: int, buf_len: int):
+    _refuse_decode(cfg)
     spec = period_spec(cfg)
     np_ = n_periods(cfg)
     one = {f"l{i}": _layer_cache(cfg, mixer, batch, buf_len)
@@ -296,9 +361,7 @@ def _layer_decode(lp, cc, x, pos, cfg: ModelConfig, mixer: str, mlp: str,
         x = x + h
     elif mlp == "moe":
         h = rms_norm(x, lp["norm2"], cfg.norm_eps)
-        x = x + moe_forward(lp["mlp"], h, n_experts=cfg.n_experts,
-                            top_k=cfg.experts_per_tok,
-                            capacity_factor=cfg.capacity_factor)
+        x = x + _moe(lp["mlp"], h, cfg)
     return x, cc
 
 
@@ -330,6 +393,7 @@ def init_paged_cache(cfg: ModelConfig, n_slots: int, n_pages: int,
     axis — ownership lives in the scheduler's page table); recurrent mixers
     (mamba/mlstm/slstm) keep their per-slot state caches, which are
     position-free and recycle via ``reset_slot``."""
+    _refuse_decode(cfg)
     spec = period_spec(cfg)
     np_ = n_periods(cfg)
     dt = dtype_of(cfg.param_dtype)
@@ -391,9 +455,7 @@ def _layer_decode_paged(lp, cc, x, positions, page_table, cfg: ModelConfig,
         x = x + h
     elif mlp == "moe":
         h = rms_norm(x, lp["norm2"], cfg.norm_eps)
-        x = x + moe_forward(lp["mlp"], h, n_experts=cfg.n_experts,
-                            top_k=cfg.experts_per_tok,
-                            capacity_factor=cfg.capacity_factor)
+        x = x + _moe(lp["mlp"], h, cfg)
     return x, cc
 
 

@@ -16,14 +16,27 @@ A scope only sets the ``op_name`` metadata of the HLO instructions traced
 inside it (``jit(_train_step)/fwd_bwd/...``): the compiled operations,
 fusions and numerics are unchanged.  ``phase_of`` reads the phase back from
 an op_name.
+
+``SPANS`` name parts of the model inside ``fwd_bwd``, in the same way:
+
+* ``mla``         -- latent attention (``models/mla.py``), projections to
+  output;
+* ``moe_route``   -- the MoE router, expert selection, the sort and gather
+  into expert order and the scatter-add that combines the results;
+* ``moe_experts`` -- the grouped matmuls over the held experts and their
+  activation.
+
+``span_of`` reads the innermost span back from an op_name.
 """
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 
 PHASES = ("fwd_bwd", "gossip", "update", "stats")
+SPANS = ("mla", "moe_route", "moe_experts")
 
 
 class phase:
@@ -56,4 +69,25 @@ def phase_of(op_name: str):
     for part in op_name.split("/"):
         if part in PHASES:
             return part
+    return None
+
+
+def span(name: str):
+    """``jax.named_scope(name)`` for one of ``SPANS``."""
+    if name not in SPANS:
+        raise ValueError(f"unknown span {name!r}; one of {SPANS}")
+    return jax.named_scope(name)
+
+
+_WRAPPED = re.compile(r"(?:[\w-]+\()*([\w-]+)\)*")
+
+
+def span_of(op_name: str):
+    """The innermost span an op_name path names, or None.  Inside a
+    differentiated function a scope's component carries the transforms
+    around it (``jvp(mla)``, ``transpose(jvp(mla))``)."""
+    for part in reversed(op_name.split("/")):
+        m = _WRAPPED.fullmatch(part)
+        if m and m.group(1) in SPANS:
+            return m.group(1)
     return None

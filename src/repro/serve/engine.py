@@ -38,12 +38,8 @@ instead of spinning: pages are only ever freed by an eviction, an eviction
 requires some slot to advance, so an all-stalled step can never make
 progress again (size ``n_pages`` for the expected concurrency instead).
 
-MoE caveat: capacity-factor routing in ``moe_forward`` drops tokens as a
-function of BATCH composition, so for moe-family models the tokens served
-for a prompt can depend on which other requests are co-scheduled (and an
-identical prompt may decode differently under different load).  Dense,
-ssm, and hybrid families are batch-composition-independent; parity tests
-pin moe only at the single-step level for this reason.
+MoE: every MoE model runs ``moe_forward``, which drops no token, so what
+a request gets back does not depend on the other requests in its batch.
 """
 from __future__ import annotations
 

@@ -164,3 +164,103 @@ def test_launch_adpsgd_and_ssgd_name_their_phases(launch_phases):
     assert set(s["phases"]) == {"fwd_bwd", "update"}
     assert s["n_permutes"] == 0
 
+
+
+@pytest.mark.parametrize("op_name,span", [
+    ("jit(train_step)/fwd_bwd/vmap(jvp())/while/body/closed_call/mla/dot_general", "mla"),
+    ("jit(train_step)/fwd_bwd/vmap(transpose(jvp(moe_experts)))/dot", "moe_experts"),
+    ("jit(train_step)/fwd_bwd/transpose(jvp(moe_route))/scatter-add", "moe_route"),
+    ("jit(train_step)/fwd_bwd/mla/moe_route/sort", "moe_route"),   # innermost
+    ("jit(train_step)/fwd_bwd/mla_extra/add", None),
+    ("jit(train_step)/gossip/shard_map/ppermute", None),
+])
+def test_span_of(op_name, span):
+    assert obs.span_of(op_name) == span
+    with pytest.raises(ValueError):
+        obs.span("attention")
+
+
+MLA_MOE = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+sys.path.insert(0, sys.argv[1])
+import dataclasses, json
+import jax, jax.numpy as jnp
+from repro.configs import get_config
+from repro.launch import sharding as shd
+from repro.launch.mesh import make_mesh
+from repro.launch.train import (jit_train_step, make_dpsgd_train_step,
+                                train_state_shardings, train_state_specs)
+from repro.models.model import build_model
+from repro.obs import phase_of, span_of
+from repro.optim import sgd
+
+cfg = dataclasses.replace(
+    get_config("moonlight-16b-a3b"), n_layers=3, d_model=64, n_heads=4,
+    n_kv_heads=4, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=16,
+    v_head_dim=16, n_experts=16, experts_held=(0, 8), experts_per_tok=4,
+    d_ff=32, shared_d_ff=32, dense_d_ff=96, vocab=512, attn_chunk=8,
+    param_dtype="float32", compute_dtype="float32")
+mesh = make_mesh((4, 1), ("data", "model"))
+api = build_model(cfg)
+opt = sgd(0.1, momentum=0.9)
+step = make_dpsgd_train_step(api, opt, mesh, topology="ring",
+                             gossip_backend="ppermute")
+specs = train_state_specs(api, opt, mesh, algo="dpsgd")
+shds = shd.named_shardings(train_state_shardings(specs, mesh, algo="dpsgd"),
+                           mesh)
+bshd = shd.named_shardings(shd.batch_sharding(api.train_batch_spec(8, 16),
+                                              mesh, stacked=False), mesh)
+with jax.set_mesh(mesh):
+    fn = jit_train_step(step, in_shardings=(shds, bshd),
+                        out_shardings=(shds, None))
+    p = jax.vmap(api.init)(jax.random.split(jax.random.PRNGKey(0), 4))
+    state = type(specs)(params=p, opt_state=jax.vmap(opt.init)(p),
+                        step=jnp.zeros((), jnp.int32),
+                        rng=jax.random.PRNGKey(1))
+    state = jax.device_put(state, shds)
+    tok = jax.random.randint(jax.random.PRNGKey(2), (8, 16), 0, 512)
+    batch = jax.device_put({"tokens": tok, "labels": tok,
+                            "mask": jnp.ones((8, 16))}, bshd)
+    text = fn.lower(state, batch).compile().as_text()
+    _, metrics = fn(state, batch)
+import re
+spans = {}
+for op_name in re.findall(r'op_name="([^"]*)"', text):
+    s = span_of(op_name)
+    if s:
+        spans.setdefault(s, set()).add(str(phase_of(op_name)))
+print(json.dumps({"spans": {k: sorted(v) for k, v in spans.items()},
+                  "metrics": {k: float(v) for k, v in metrics.items()}}))
+"""
+
+
+@pytest.fixture(scope="module")
+def mla_moe_launch():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", MLA_MOE,
+                        os.path.dirname(os.path.abspath(__file__))], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-4000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def test_launch_mla_moe_spans_sit_inside_fwd_bwd(mla_moe_launch):
+    """The launch DPSGD step of an MLA/MoE model on four devices: its ops
+    name the three model spans, inside the ``fwd_bwd`` phase and no other
+    (a few ops XLA hoists or recomputes keep the span but lose the outer
+    path, and so carry no phase)."""
+    spans = mla_moe_launch["spans"]
+    assert set(spans) == set(obs.SPANS)
+    for phases in spans.values():
+        assert "fwd_bwd" in phases, spans
+        assert set(phases) <= {"fwd_bwd", "None"}, spans
+
+
+def test_launch_dpsgd_reports_the_moe_counter(mla_moe_launch):
+    """The step's metrics carry the mean over learners of the held experts'
+    assignments: 2 MoE layers x 32 tokens x 4 experts, 8 of 16 held."""
+    m = mla_moe_launch["metrics"]
+    assert set(m) == {"loss", "moe_held_assignments"}
+    assert 0 < m["moe_held_assignments"] < 2 * 32 * 4

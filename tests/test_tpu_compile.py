@@ -197,3 +197,48 @@ def test_launch_ring_step_phases_on_four_chips(v5e_2x2):
                 if op.startswith("collective-permute")}
     assert convs == {"fwd_bwd"} and permutes == {"gossip"}
     assert set(phases.values()) == set(obs.PHASES)
+
+
+def test_moonlight_share_launch_step_fits_a_v5e(v5e_2x2):
+    """One chip's share of Moonlight-16B-A3B (the benchmark cell's: the
+    dense layer and 4 MoE layers of 8 held experts, a 20,480-row vocabulary
+    slice, float32) in the launch DPSGD step with ring gossip, one learner
+    per described chip, at local batch 2 x 8,192: it compiles within a
+    v5e's 16 GB, the held experts run as the TPU's grouped matmul, and the
+    model spans are in the compiled step."""
+    import re
+
+    from bench.harness import cell, mla_moe
+    from repro.launch import sharding as shd
+    from repro.launch.mesh import make_mesh
+    from repro.launch.train import (jit_train_step, make_dpsgd_train_step,
+                                    train_state_shardings, train_state_specs)
+    from repro.obs import SPANS, span_of
+    from repro.optim import sgd
+    d = v5e_2x2
+    mesh = make_mesh((4, 1), ("data", "model"),
+                     devices=[d[0], d[1], d[3], d[2]])
+    api = mla_moe.build(cell.load_json(
+        cell.BENCH / "configs" / "moonlight-16b-a3b.json"))
+    opt = sgd(0.5, momentum=0.9)
+    step = make_dpsgd_train_step(api, opt, mesh, topology="ring",
+                                 gossip_backend="ppermute")
+    specs = train_state_specs(api, opt, mesh, algo="dpsgd")
+    shds = shd.named_shardings(
+        train_state_shardings(specs, mesh, algo="dpsgd"), mesh)
+    bspecs = api.train_batch_spec(4 * 2, 8192)
+    bshd = shd.named_shardings(
+        shd.batch_sharding(bspecs, mesh, stacked=False), mesh)
+
+    def placed(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, s: _spec(a.shape, a.dtype, s), tree, shardings)
+    with jax.set_mesh(mesh):
+        compiled = jit_train_step(
+            step, in_shardings=(shds, bshd), out_shardings=(shds, None)
+        ).lower(placed(specs, shds), placed(bspecs, bshd)).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes < 16e9
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    found = {span_of(n) for n in re.findall(r'op_name="([^"]*)"', text)}
+    assert set(SPANS) <= found
