@@ -1,12 +1,20 @@
-"""jit'd public wrappers around the Pallas kernels.
+"""jit'd public wrappers around the Pallas kernels, in the models' layouts.
 
-`flash_attention` accepts the model-layout (B, S, H, hd) tensors used by
-repro.models.attention and adds a custom VJP whose backward pass is the
-jnp reference gradient (forward runs the kernel; backward recomputes through
-the oracle — numerically identical, documented trade-off).
+Each kernel compiles to Mosaic on the TPU.  On the CPU (``on_cpu``) it runs
+in interpret mode, which exists to verify it: a dispatcher with
+``backend='auto'`` picks its jnp oracle there instead.
 
-On CPU (this container) the kernels run in interpret mode automatically;
-on TPU they compile to Mosaic.
+* ``flash_attention``: (B, S, H, hd) attention for the opt-in
+  ``use_pallas`` path; the forward is the kernel, the backward recomputes
+  through the jnp reference.
+* ``causal_flash_attention``: latent attention's core, causal, with q/k
+  and v of different widths; forward and backward are kernels
+  (``causal_flash.py``), one call per learner under a vmap over learners.
+  ``models/mla.py`` runs it on the TPU and ``chunked_attention`` on the
+  CPU.
+* ``paged_decode_attention``, ``reorthogonalize``, ``flat_gossip_update``,
+  ``flat_gossip_mix`` and ``dpsgd_fused_update``: serving decode, the
+  landscape probe and the gossip updates.
 """
 from __future__ import annotations
 
@@ -15,7 +23,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from ..models.shard_hints import per_learner
 from . import ref
+from .causal_flash import causal_flash_bwd, causal_flash_fwd
 from .decode_attention import paged_decode_attention_fwd
 from .flash_attention import flash_attention_fwd
 from .gossip_mix import (flatten_for_kernel, gossip_mix_update,
@@ -23,7 +33,7 @@ from .gossip_mix import (flatten_for_kernel, gossip_mix_update,
 from .reorth import reorth_pass
 
 
-def _on_cpu() -> bool:
+def on_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
@@ -34,7 +44,7 @@ def _flash(q, k, v, causal, window, attn_softcap, q_positions, k_positions):
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
     o = flash_attention_fwd(qt, kt, vt, causal=causal, window=window,
-                            attn_softcap=attn_softcap, interpret=_on_cpu())
+                            attn_softcap=attn_softcap, interpret=on_cpu())
     return o.transpose(0, 2, 1, 3)
 
 
@@ -74,6 +84,39 @@ def flash_attention(q, k, v, *, q_positions=None, k_positions=None,
                   k_positions)
 
 
+def _bhsd(x):
+    return x.transpose(0, 2, 1, 3)
+
+
+_causal_fwd = per_learner(
+    lambda q, k, v: causal_flash_fwd(q, k, v, interpret=on_cpu()))
+_causal_bwd = per_learner(
+    lambda q, k, v, o, lse, do: causal_flash_bwd(q, k, v, o, lse, do,
+                                                 interpret=on_cpu()))
+
+
+@jax.custom_vjp
+def causal_flash_attention(q, k, v):
+    """Causal attention, q, k: (B, S, H, dk); v: (B, S, H, dv) ->
+    (B, S, H, dv), positions 0..S-1, scale dk ** -0.5.  Forward and
+    backward run the fused kernels, once per learner under a vmap over
+    learners (the compiler cannot partition a Mosaic call)."""
+    return _causal_flash_fwd(q, k, v)[0]
+
+
+def _causal_flash_fwd(q, k, v):
+    q, k, v = _bhsd(q), _bhsd(k), _bhsd(v)
+    o, lse = _causal_fwd(q, k, v)
+    return _bhsd(o), (q, k, v, o, lse)
+
+
+def _causal_flash_bwd(res, g):
+    return tuple(_bhsd(d) for d in _causal_bwd(*res, _bhsd(g)))
+
+
+causal_flash_attention.defvjp(_causal_flash_fwd, _causal_flash_bwd)
+
+
 def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
                            window: int = 0, attn_softcap: float = 0.0,
                            backend: str = "auto"):
@@ -88,14 +131,14 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
     tests force ``backend='pallas'`` for that).  Inference-only: no VJP.
     """
     if backend == "auto":
-        backend = "ref" if _on_cpu() else "pallas"
+        backend = "ref" if on_cpu() else "pallas"
     if backend == "ref":
         return ref.paged_decode_attention_ref(
             q, k_pages, v_pages, page_table, lengths, window=window,
             attn_softcap=attn_softcap)
     return paged_decode_attention_fwd(
         q, k_pages, v_pages, page_table, lengths, window=window,
-        attn_softcap=attn_softcap, interpret=_on_cpu())
+        attn_softcap=attn_softcap, interpret=on_cpu())
 
 
 def reorthogonalize(basis, w, mask, *, backend: str = "pallas"):
@@ -112,7 +155,7 @@ def reorthogonalize(basis, w, mask, *, backend: str = "pallas"):
         w, _ = ref.reorth_ref(basis, w, mask)
         w, _ = ref.reorth_ref(basis, w, mask)
         return w
-    interpret = _on_cpu()
+    interpret = on_cpu()
     w, _ = reorth_pass(basis, w, mask, interpret=interpret)
     w, _ = reorth_pass(basis, w, mask, interpret=interpret)
     return w
@@ -139,7 +182,7 @@ def flat_gossip_update(w, remote, grads, momentum, partners, coefs, *,
     has_momentum = momentum is not None
     mu = momentum if has_momentum else w      # ignored when has_momentum=False
     if backend == "auto":
-        backend = "ref" if _on_cpu() else "pallas"
+        backend = "ref" if on_cpu() else "pallas"
     if backend == "ref":
         out = ref.gossip_mix_update_flat_ref(
             w, remote, grads, mu, partners, coefs, lr=lr, beta=beta,
@@ -149,7 +192,7 @@ def flat_gossip_update(w, remote, grads, momentum, partners, coefs, *,
         out = gossip_mix_update_flat(
             w, remote, grads, mu, partners, coefs, lr=lr, beta=beta,
             weight_decay=weight_decay, has_momentum=has_momentum,
-            buffer=buffer, interpret=_on_cpu())
+            buffer=buffer, interpret=on_cpu())
     w_new, mu_new = out[0], (out[1] if has_momentum else None)
     if buffer is not None:
         return w_new, mu_new, out[2]
@@ -198,5 +241,5 @@ def dpsgd_fused_update(params_tree, neighbor_trees, grads_tree, momentum_tree,
     nbrs = jnp.stack([flatten_for_kernel(t)[0] for t in neighbor_trees])
     w_new, mu_new = gossip_mix_update(w, nbrs, g, mu,
                                       jnp.asarray(coefs, jnp.float32),
-                                      lr=lr, beta=beta, interpret=_on_cpu())
+                                      lr=lr, beta=beta, interpret=on_cpu())
     return unflatten_w(w_new), unflatten_mu(mu_new)

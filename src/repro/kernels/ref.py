@@ -139,3 +139,15 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bksd->bkgqd", p, vf)
     return o.reshape(B, H, Sq, hd).astype(q.dtype)
+
+
+def causal_flash_ref(q, k, v):
+    """Same contract as kernels.causal_flash (forward): q, k: (B, H, S, dk);
+    v: (B, H, S, dv) -> (B, H, S, dv).  One causal softmax over positions
+    0..S-1, f32 at the highest precision."""
+    S = q.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision="highest") * q.shape[-1] ** -0.5
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, NEG_INF)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")

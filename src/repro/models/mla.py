@@ -11,9 +11,12 @@ With ``q_lora_rank`` 0 (q is one plain projection), for x: (B, S, d):
                                     / sqrt(qk_nope + qk_rope)) v
     out            = [o_1 | ... | o_H] W_o
 
-The core is ``attention.chunked_attention`` (blocked over queries and keys
-with an online softmax, each query block recomputed in the backward pass),
-with q/k ``qk_nope + qk_rope`` wide and v ``v_head_dim`` wide.
+The core is causal attention with q/k ``qk_nope + qk_rope`` wide and v
+``v_head_dim`` wide.  On the TPU it is the fused flash kernel, forward and
+backward (``kernels.ops.causal_flash_attention``: score tiles stay in
+VMEM, tiles above the diagonal are skipped).  On the CPU it is its oracle,
+``attention.chunked_attention`` (blocked over queries and keys with an
+online softmax, each query block recomputed in the backward pass).
 
 One departure from the published checkpoint's layout: RoPE rotates the two
 halves of the rope dims (rotate-half) where the checkpoint rotates
@@ -50,11 +53,13 @@ def init_mla_params(key, cfg, dtype):
 
 
 def mla_forward(params, x, cfg, positions):
-    """x: (B, S, d); positions: (S,) -> (B, S, d), causal."""
+    """x: (B, S, d); positions: (S,), 0..S-1 as training gives them (the
+    TPU kernel masks by index) -> (B, S, d), causal."""
     B, S, _ = x.shape
     H, r = cfg.n_heads, cfg.kv_lora_rank
     nope, rope, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
+    from ..kernels import ops
     with span("mla"):
         q = (x @ params["wq"]).reshape(B, S, H, nope + rope)
         kva = x @ params["wkv_a"]
@@ -66,7 +71,11 @@ def mla_forward(params, x, cfg, positions):
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_pe, (B, S, H, rope))],
             axis=-1)
-        o = chunked_attention(q, k, kv[..., nope:], q_positions=positions,
-                              k_positions=positions,
-                              chunk=min(cfg.attn_chunk, S))
+        v = kv[..., nope:]
+        if ops.on_cpu():
+            o = chunked_attention(q, k, v, q_positions=positions,
+                                  k_positions=positions,
+                                  chunk=min(cfg.attn_chunk, S))
+        else:
+            o = ops.causal_flash_attention(q, k, v)
         return o.reshape(B, S, H * vd) @ params["wo"]

@@ -29,11 +29,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from jax.sharding import PartitionSpec as P
-
 from ..obs import span
 from .layers import dense_init
-from .shard_hints import learner_axes
+from .shard_hints import per_learner
 
 __all__ = ["grouped_matmul", "init_moe_params", "moe_forward", "route"]
 
@@ -94,46 +92,16 @@ def _swiglu(x, p):
 # grouped matmul, one learner at a time
 #
 # The TPU's grouped matmul (XLA's ragged dot) takes no batch dimension, so a
-# vmap over learners must not batch it.  Its forward and its backward each
-# run under ``custom_vmap`` as one unbatched call per learner: inside the
-# DPSGD steps, whose vmap is sharded over the learner mesh axes
-# (``shard_hints.vmapped_learner_axes``), each device maps over its own
-# learners in ``shard_map``; elsewhere (learners stacked on one device) a
-# ``lax.map`` does.
+# vmap over learners must not batch it: its forward and its backward each
+# run as one unbatched call per learner (``shard_hints.per_learner``).
 # ---------------------------------------------------------------------------
-
-def _map_learners(fn, args):
-    def local(*a):
-        if a[0].shape[0] == 1:
-            return jax.tree_util.tree_map(lambda o: o[None],
-                                          fn(*(x[0] for x in a)))
-        return jax.lax.map(lambda t: fn(*t), a)
-    axes = learner_axes()
-    if not axes:
-        return local(*args)
-    spec = P(axes)
-    return jax.shard_map(local, in_specs=(spec,) * len(args),
-                         out_specs=spec, check_vma=False)(*args)
-
-
-def _per_learner(fn):
-    f = jax.custom_batching.custom_vmap(fn)
-
-    @f.def_vmap
-    def rule(axis_size, in_batched, *args):
-        args = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
-                for a, b in zip(args, in_batched)]
-        out = _map_learners(fn, args)
-        return out, jax.tree_util.tree_map(lambda _: True, out)
-    return f
-
 
 def _ragged(x, w, sizes):
     return jax.lax.ragged_dot(x, w, sizes)
 
 
-_gmm_fwd = _per_learner(_ragged)
-_gmm_bwd = _per_learner(
+_gmm_fwd = per_learner(_ragged)
+_gmm_bwd = per_learner(
     lambda x, w, sizes, g: jax.vjp(lambda a, b: _ragged(a, b, sizes),
                                    x, w)[1](g))
 

@@ -5,6 +5,7 @@ under the production mesh (dry-run / real launch)."""
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
@@ -82,6 +83,41 @@ def learner_axes() -> tuple:
     """The mesh axes the current vmap over learners is sharded over, or ()
     (no mesh: the learners share one device)."""
     return getattr(_CTX, "learner_axes", ())
+
+
+def map_learners(fn, args):
+    """``fn`` once per learner over the leading (learner) axis of ``args``,
+    unbatched: inside a vmap sharded over the learner mesh axes
+    (``vmapped_learner_axes``) each device maps over its own learners in
+    ``shard_map``; elsewhere (learners stacked on one device) a
+    ``lax.map`` does."""
+    def local(*a):
+        if a[0].shape[0] == 1:
+            return jax.tree_util.tree_map(lambda o: o[None],
+                                          fn(*(x[0] for x in a)))
+        return jax.lax.map(lambda t: fn(*t), a)
+    axes = learner_axes()
+    if not axes:
+        return local(*args)
+    spec = P(axes)
+    return jax.shard_map(local, in_specs=(spec,) * len(args),
+                         out_specs=spec, check_vma=False)(*args)
+
+
+def per_learner(fn):
+    """``fn`` under ``custom_vmap``: a vmap over learners runs it as one
+    unbatched call per learner (``map_learners``).  For what takes no
+    batch dimension or cannot be partitioned by the compiler: the TPU's
+    ragged dot, a Mosaic kernel."""
+    f = jax.custom_batching.custom_vmap(fn)
+
+    @f.def_vmap
+    def rule(axis_size, in_batched, *args):
+        args = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args, in_batched)]
+        out = map_learners(fn, args)
+        return out, jax.tree_util.tree_map(lambda _: True, out)
+    return f
 
 
 def residual_hint(x):

@@ -5,12 +5,15 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
+from repro.kernels.causal_flash import causal_flash_bwd, causal_flash_fwd
 from repro.kernels.decode_attention import paged_decode_attention_fwd
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.gossip_mix import (flatten_for_kernel, gossip_mix_update,
                                      gossip_mix_update_flat)
-from repro.kernels.ops import (dpsgd_fused_update, flash_attention,
-                               flat_gossip_update, paged_decode_attention)
+from repro.kernels.ops import (causal_flash_attention, dpsgd_fused_update,
+                               flash_attention, flat_gossip_update,
+                               paged_decode_attention)
+from repro.models.attention import chunked_attention
 
 
 @pytest.mark.parametrize("T,K", [(256, 1), (512, 2), (1024, 3)])
@@ -50,6 +53,140 @@ def test_flash_attention_sweep(S, hd, H, KV, dtype, kw):
     atol = 2e-6 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(o1, np.float32),
                                np.asarray(o2, np.float32), atol=atol)
+
+
+def _chunked_bhsd(q, k, v):
+    t = lambda x: x.transpose(0, 2, 1, 3)
+    pos = jnp.arange(q.shape[2])
+    return t(chunked_attention(t(q), t(k), t(v), q_positions=pos,
+                               k_positions=pos, chunk=64))
+
+
+def _rel(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+# The kernels round each dot's operands to bfloat16 once (XLA's DEFAULT
+# precision for an f32 matmul on the TPU): 2^-8 relative per operand.
+CAUSAL_FLASH_REL = 1e-2
+
+
+@pytest.mark.parametrize("B,H,S,dk,dv,bq,bk", [
+    (1, 1, 256, 48, 32, 64, 64),
+    (1, 2, 256, 48, 32, 128, 64),
+    (1, 2, 256, 64, 32, 64, 128),
+    (1, 1, 64, 48, 32, 512, 512),
+    (2, 3, 128, 48, 48, 64, 64),
+], ids=["qk_ne_v", "bq_gt_bk", "bq_lt_bk", "single_block", "batch_heads"])
+def test_causal_flash_matches_oracles(B, H, S, dk, dv, bq, bk):
+    """Forward output and the gradients of q, k and v of the causal flash
+    kernels (interpret mode) against ``chunked_attention`` and a plain
+    softmax, at a tolerance set by the bf16 rounding; and nothing after a
+    query reaches it."""
+    ks = jax.random.split(jax.random.PRNGKey(S + dk + bq), 5)
+    q = jax.random.normal(ks[0], (B, H, S, dk))
+    k = jax.random.normal(ks[1], (B, H, S, dk))
+    v = jax.random.normal(ks[2], (B, H, S, dv))
+    w = jax.random.normal(ks[3], (B, H, S, dv))
+    kw = dict(block_q=bq, block_k=bk, interpret=True)
+    o, lse = causal_flash_fwd(q, k, v, **kw)
+    got = (o,) + causal_flash_bwd(q, k, v, o, lse, w, **kw)
+    for oracle in (ref.causal_flash_ref, _chunked_bhsd):
+        want = (oracle(q, k, v),) + jax.grad(
+            lambda *a: jnp.sum(oracle(*a) * w), argnums=(0, 1, 2))(q, k, v)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            assert _rel(a, b) < CAUSAL_FLASH_REL, (oracle.__name__, name,
+                                                   _rel(a, b))
+    t = S // 2 + 3
+    later = jnp.arange(S)[None, None, :, None] >= t
+    o2, lse2 = causal_flash_fwd(q, jnp.where(later, 7.0, k),
+                                jnp.where(later, -7.0, v), **kw)
+    np.testing.assert_array_equal(np.asarray(o2[:, :, :t]),
+                                  np.asarray(o[:, :, :t]))
+    np.testing.assert_array_equal(np.asarray(lse2[..., :t]),
+                                  np.asarray(lse[..., :t]))
+
+
+def test_causal_flash_attention_model_layout_grad():
+    """``ops.causal_flash_attention`` in the model layout (B, S, H, d):
+    ``jax.grad`` through its custom VJP matches ``chunked_attention``."""
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    q = jax.random.normal(ks[0], (2, 128, 2, 48))
+    k = jax.random.normal(ks[1], (2, 128, 2, 48))
+    v = jax.random.normal(ks[2], (2, 128, 2, 32))
+    w = jax.random.normal(ks[3], (2, 128, 2, 32))
+    pos = jnp.arange(128)
+
+    def loss(f):
+        return lambda *a: jnp.sum(f(*a) * w)
+    chunked = lambda *a: chunked_attention(*a, q_positions=pos,
+                                           k_positions=pos, chunk=64)
+    got = jax.value_and_grad(loss(causal_flash_attention),
+                             argnums=(0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(loss(chunked), argnums=(0, 1, 2))(q, k, v)
+    assert abs(float(got[0] - want[0])) < CAUSAL_FLASH_REL * abs(
+        float(want[0])) + 1e-3
+    for a, b in zip(got[1], want[1]):
+        assert _rel(a, b) < CAUSAL_FLASH_REL
+
+
+PER_LEARNER_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import json
+import jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.kernels.ops import causal_flash_attention
+from repro.launch.mesh import make_mesh
+from repro.models.shard_hints import vmapped_learner_axes
+
+mesh = make_mesh((2, 1), ("data", "model"), devices=jax.devices()[:2])
+ks = jax.random.split(jax.random.PRNGKey(0), 4)
+q = jax.random.normal(ks[0], (2, 1, 128, 2, 48))
+k = jax.random.normal(ks[1], (2, 1, 128, 2, 48))
+v = jax.random.normal(ks[2], (2, 1, 128, 2, 32))
+w = jax.random.normal(ks[3], (2, 1, 128, 2, 32))
+
+def one(q, k, v, w):
+    return jax.value_and_grad(
+        lambda *a: jnp.sum(causal_flash_attention(*a) * w),
+        argnums=(0, 1, 2))(q, k, v)
+
+def learners(q, k, v, w):
+    with vmapped_learner_axes(("data",)):
+        return jax.vmap(one, spmd_axis_name=("data",))(q, k, v, w)
+
+shd = NamedSharding(mesh, P("data"))
+with jax.set_mesh(mesh):
+    fn = jax.jit(learners, in_shardings=(shd,) * 4)
+    got = fn(q, k, v, w)
+    text = fn.lower(q, k, v, w).as_text()
+want = [one(q[i], k[i], v[i], w[i]) for i in range(2)]
+gap = max(float(jnp.max(jnp.abs(a[i] - b)))
+          for i in range(2)
+          for a, b in zip(jax.tree_util.tree_leaves(got),
+                          jax.tree_util.tree_leaves(want[i])))
+print(json.dumps({"gap": gap, "shard_map": "manual_computation" in text}))
+"""
+
+
+def test_causal_flash_per_learner_vmap_on_a_mesh():
+    """Under a 2-learner vmap sharded over a 2-device CPU mesh (the DPSGD
+    steps' ``spmd_axis_name``), the kernels run once per learner in
+    ``shard_map`` and match the unbatched call, value and gradients."""
+    import json
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+        [p for p in ("src", os.environ.get("PYTHONPATH")) if p]))
+    out = subprocess.run([sys.executable, "-c", PER_LEARNER_SCRIPT],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res["shard_map"]
+    assert res["gap"] < 1e-5, res
 
 
 def _paged_operands(S, hd, H, KV, page, max_pages, lengths, seed=0):

@@ -22,6 +22,8 @@ import jax.numpy as jnp  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
 from repro.core.flatstate import flat_meta  # noqa: E402
+from repro.kernels.causal_flash import (causal_flash_dkv,  # noqa: E402
+                                        causal_flash_dq, causal_flash_fwd)
 from repro.kernels.decode_attention import \
     paged_decode_attention_fwd  # noqa: E402
 from repro.kernels.flash_attention import flash_attention_fwd  # noqa: E402
@@ -128,6 +130,63 @@ def test_flash_attention_fwd_compiles(one_chip, cfg):
     _assert_kernel(compiled, "flash_attention_fwd")
 
 
+def _mosaic_calls(text):
+    """(instruction name, op_name) of each Mosaic call in compiled HLO."""
+    import re
+    return [(re.search(r"%(\S+) = ", line).group(1),
+             re.search(r'op_name="([^"]*)"', line).group(1))
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("kernel", ["causal_flash_fwd", "causal_flash_dq",
+                                    "causal_flash_dkv"])
+def test_causal_flash_compiles_at_moonlight_shapes(one_chip, kernel):
+    """Latent attention's kernels at Moonlight-16B-A3B's training shapes:
+    batch 2, 16 heads, seq 8,192, q/k 192 wide, v 128, float32."""
+    B, H, S = 2, 16, 8192
+    qk = _spec((B, H, S, 192), jnp.float32, one_chip)
+    v = _spec((B, H, S, 128), jnp.float32, one_chip)
+    row = _spec((B, H, 1, S), jnp.float32, one_chip)
+    fn, args = {"causal_flash_fwd": (causal_flash_fwd, (qk, qk, v)),
+                "causal_flash_dq": (causal_flash_dq, (qk, qk, v, v, row, row)),
+                "causal_flash_dkv": (causal_flash_dkv,
+                                     (qk, qk, v, v, row, row))}[kernel]
+    _assert_kernel(jax.jit(fn).lower(*args).compile(), kernel)
+
+
+def test_mla_grad_runs_the_kernels_inside_its_span(one_chip, monkeypatch):
+    """``jax.grad`` of ``mla_forward`` as the TPU compiles it (small
+    widths, seq 256): the core is the forward kernel and both backward
+    kernels, and each call's op_name maps to the ``mla`` span, so that
+    ``span_ms.mla`` keeps counting the attention."""
+    import dataclasses
+
+    from repro.kernels import ops
+    from repro.models.mla import init_mla_params, mla_forward
+    from repro.obs import span_of
+    monkeypatch.setattr(ops, "on_cpu", lambda: False)
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b"), d_model=64,
+                              n_heads=4, kv_lora_rank=32,
+                              qk_nope_head_dim=16, qk_rope_head_dim=16,
+                              v_head_dim=16)
+    S = 256
+    p = jax.tree_util.tree_map(
+        lambda a: _spec(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: init_mla_params(jax.random.PRNGKey(0), cfg,
+                                               jnp.float32)))
+    x = _spec((2, S, cfg.d_model), jnp.float32, one_chip)
+
+    def loss(p, x):
+        return jnp.sum(mla_forward(p, x, cfg, jnp.arange(S)) ** 2)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile(
+    ).as_text()
+    calls = _mosaic_calls(text)
+    assert sorted(n.split(".")[0] for n, _ in calls) == [
+        "causal_flash_dkv", "causal_flash_dq", "causal_flash_fwd"]
+    assert {span_of(op) for _, op in calls} == {"mla"}, calls
+
+
 def test_gossip_mix_update_compiles(one_chip):
     """The single-learner gossip + momentum kernel, pair gossip (K=1)."""
     T = 4096
@@ -199,22 +258,25 @@ def test_launch_ring_step_phases_on_four_chips(v5e_2x2):
     assert set(phases.values()) == set(obs.PHASES)
 
 
-def test_moonlight_share_launch_step_fits_a_v5e(v5e_2x2):
+def test_moonlight_share_launch_step_fits_a_v5e(v5e_2x2, monkeypatch):
     """One chip's share of Moonlight-16B-A3B (the benchmark cell's: the
     dense layer and 4 MoE layers of 8 held experts, a 20,480-row vocabulary
     slice, float32) in the launch DPSGD step with ring gossip, one learner
-    per described chip, at local batch 2 x 8,192: it compiles within a
-    v5e's 16 GB, the held experts run as the TPU's grouped matmul, and the
-    model spans are in the compiled step."""
+    per described chip, at local batch 2 x 8,192, as the TPU runs it: it
+    compiles within a v5e's 16 GB, the held experts run as the TPU's
+    grouped matmul, latent attention as the causal flash kernels inside
+    the ``mla`` span, and the model spans are in the compiled step."""
     import re
 
     from bench.harness import cell, mla_moe
+    from repro.kernels import ops
     from repro.launch import sharding as shd
     from repro.launch.mesh import make_mesh
     from repro.launch.train import (jit_train_step, make_dpsgd_train_step,
                                     train_state_shardings, train_state_specs)
     from repro.obs import SPANS, span_of
     from repro.optim import sgd
+    monkeypatch.setattr(ops, "on_cpu", lambda: False)
     d = v5e_2x2
     mesh = make_mesh((4, 1), ("data", "model"),
                      devices=[d[0], d[1], d[3], d[2]])
@@ -242,3 +304,8 @@ def test_moonlight_share_launch_step_fits_a_v5e(v5e_2x2):
     assert "ragged-dot" in text
     found = {span_of(n) for n in re.findall(r'op_name="([^"]*)"', text)}
     assert set(SPANS) <= found
+    calls = [(n, op) for n, op in _mosaic_calls(text)
+             if n.startswith("causal_flash")]
+    assert {n.split(".")[0] for n, _ in calls} == {
+        "causal_flash_fwd", "causal_flash_dq", "causal_flash_dkv"}
+    assert {span_of(op) for _, op in calls} == {"mla"}, calls
